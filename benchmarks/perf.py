@@ -29,8 +29,9 @@ With `--devices N` (N > 1) a fifth scenario rides along:
 
   grid_sharded — the grid sweep with its stacked rows sharded over N
             devices (runner `_row_sharding`/`_pad_rows`); if fewer
-            devices are visible the benchmark re-executes itself with
-            `--xla_force_host_platform_device_count=N`. Under
+            devices are visible on the CPU the benchmark re-executes
+            itself with `--xla_force_host_platform_device_count=N`, and
+            on an accelerator it stops with an error. Under
             `--compare` it is timed cold like `grid`, new-side sharded
             vs old-side single-device, at a disjoint cycle count so
             neither side reuses the `grid` round's compiles.
@@ -53,10 +54,11 @@ times them back-to-back (pair-by-pair) so neighbor drift hits both
 sides equally; the reported number is the median new/old speedup per
 scenario, never a cross-run absolute.
 
-Compiles are cached persistently under `.jax_cache/` (repo root) so
-repeated invocations skip XLA recompiles; disable with
-`--no-compile-cache`. `--compare` removes its materialized baseline
-tree on exit unless `--keep-baseline`.
+Compiles are cached persistently in `$JAX_COMPILATION_CACHE_DIR` when
+that is set, else under `.jax_cache/` (repo root), so repeated
+invocations skip XLA recompiles; disable with `--no-compile-cache`.
+`--compare` removes its materialized baseline tree on exit unless
+`--keep-baseline`.
 
 Run:  PYTHONPATH=src python -m benchmarks.perf [--cycles N] [--rounds R]
       PYTHONPATH=src python -m benchmarks.perf --compare HEAD
@@ -106,15 +108,23 @@ GIT_TIMEOUT_S = 120
 REEXEC_TIMEOUT_S = 4 * 3600
 
 
-def enable_compilation_cache(cache_dir: Path = CACHE_DIR) -> None:
-    """Enable JAX's persistent compilation cache under `cache_dir` so
-    repeated benchmark invocations skip recompiles (opt out with
-    --no-compile-cache; see README "Performance")."""
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+def enable_compilation_cache(cache_dir: Path = CACHE_DIR) -> str:
+    """Enable JAX's persistent compilation cache so repeated invocations
+    skip recompiles (opt out with --no-compile-cache; see README
+    "Persistent compilation cache"); returns the directory in use.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX has taken its directory
+    from it and that setting is left alone. Otherwise the cache goes to
+    the fixed `cache_dir`: never a path made from a temporary name, a
+    process id or the time, or later runs would never hit it."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     # cache every entry, however small/fast — sim compiles are the cost
     # (0, not the default 1s: CI-smoke-scale programs compile sub-second)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return env_dir or str(cache_dir)
 
 
 def _mk_cfg(config_mod, **kw):
@@ -292,10 +302,7 @@ def _materialize_baseline(ref: str) -> str:
             cwd=REPO_ROOT, capture_output=True, check=True,
             timeout=GIT_TIMEOUT_S).stdout
         with tarfile.open(fileobj=BytesIO(tar_bytes)) as tf:
-            try:
-                tf.extractall(tmp, filter="data")
-            except TypeError:            # Python < 3.12
-                tf.extractall(tmp)
+            tf.extractall(tmp, filter="data")
         (tmp / "src" / "repro").rename(tmp / "src" / "repro_base")
         for py in (tmp / "src" / "repro_base").rglob("*.py"):
             py.write_text(_IMPORT_RE.sub(r"\1repro_base", py.read_text()))
@@ -504,13 +511,15 @@ def main() -> None:
                          "tree after --compare (default: removed on exit)")
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="disable the persistent JAX compilation cache "
-                         "(default: cache compiles under .jax_cache/)")
+                         "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                         ".jax_cache/)")
     ap.add_argument("--devices", type=int, default=0,
                     help="shard the grid sweep's rows over N devices "
                          "(adds the grid_sharded scenario); on a CPU host "
                          "with fewer visible devices the benchmark "
                          "re-executes itself with "
-                         "--xla_force_host_platform_device_count=N")
+                         "--xla_force_host_platform_device_count=N, on "
+                         "an accelerator it fails")
     ap.add_argument("--tlb-backend", default="xla",
                     choices=["xla", "pallas", "pallas-interpret"],
                     help="fused shared-round backend for the current tree "
@@ -518,6 +527,13 @@ def main() -> None:
                          "their own default path)")
     args = ap.parse_args()
     if args.devices > 1 and jax.device_count() < args.devices:
+        if jax.default_backend() != "cpu":
+            # this process holds the accelerator now: a child could not
+            # get it, and forced host devices exist only on the CPU
+            raise SystemExit(
+                f"--devices {args.devices}: only {jax.device_count()} "
+                f"{jax.default_backend()} devices visible (fewer devices "
+                "than --devices)")
         # the device-count flag must be set before the backend exists, so
         # re-exec into a child that sees the forced host devices
         env = dict(os.environ)

@@ -47,10 +47,15 @@ def init(n_channels: int, n_banks: int, n_apps: int) -> DramState:
 
 
 def silver_quota(state: DramState, thres_max=500) -> jax.Array:
-    """(n_apps,) Eq. (1) thresholds."""
-    w = (state.conc_walks * state.warps_stalled).astype(jnp.float32)
-    tot = jnp.maximum(w.sum(), 1.0)
-    return jnp.maximum((thres_max * w / tot).astype(jnp.int32), 1)
+    """(n_apps,) Eq. (1) thresholds, in exact integer arithmetic: a float
+    quotient truncated to an int can land on either side of an integer
+    depending on how the backend rounds division (the TPU's need not be
+    exactly rounded). Products stay far below 2**31: Concurrent_i is at
+    most the walk table's rows (64 in Table 1) and WrpStalled_i at most
+    the warp count."""
+    w = state.conc_walks * state.warps_stalled
+    tot = jnp.maximum(w.sum(), 1)
+    return jnp.maximum(thres_max * w // tot, 1)
 
 
 def classify(state: DramState, app, is_tlb, mask_enabled):
@@ -147,12 +152,20 @@ def access(state: DramState, channel, bank, row, app, is_tlb, active,
     latency = jnp.where(active, latency, 0)
 
     # ---- state updates ----
-    # open rows: last active request per (channel, bank) wins; inactive
-    # lanes are routed out of bounds and dropped — a masked write-back of
-    # the gathered value would let a trailing inactive lane clobber an
-    # earlier active lane's update with the stale cycle-start row
+    # open rows: last active request per (channel, bank) wins. The winner
+    # is picked explicitly (a scatter-max of lane order, which commutes)
+    # because XLA leaves the order of a scatter's duplicate updates to the
+    # backend: the CPU applies them in lane order, a TPU need not. Losing
+    # and inactive lanes are routed out of bounds and dropped — a masked
+    # write-back of the gathered value would let a trailing inactive lane
+    # clobber an earlier active lane's update with the stale row
+    n_cb = n_channels * n_banks
+    cb_lane = jnp.where(active, channel * n_banks + bank, n_cb)
+    lane = jnp.arange(N, dtype=jnp.int32)
+    last = jnp.full((n_cb,), -1, jnp.int32).at[cb_lane].max(lane, mode="drop")
+    win = active & (last[jnp.minimum(cb_lane, n_cb - 1)] == lane)
     new_open = state.open_row.at[
-        jnp.where(active, channel, n_channels), bank].set(row, mode="drop")
+        jnp.where(win, channel, n_channels), bank].set(row, mode="drop")
 
     # silver rotation: consume quota per wave (at most one rotation per
     # wave, like the sequential per-round calls); classification keeps the

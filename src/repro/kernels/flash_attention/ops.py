@@ -1,4 +1,4 @@
-"""jit'd public wrapper: layout handling, GQA, CPU-interpret fallback."""
+"""jit'd public wrapper: layout handling, GQA, explicit interpret mode."""
 from __future__ import annotations
 
 import functools
@@ -7,11 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -21,11 +18,11 @@ def flash_attention(q, k, v, *, causal=True, window: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """q: (B, S, H, dh); k, v: (B, S, KV, dh) — model-native layout.
 
-    Returns (B, S, H, dh). On CPU the kernel body runs in interpret mode
-    (correctness path); on TPU it compiles to Mosaic.
+    Returns (B, S, H, dh). `interpret=None` compiles to Mosaic on a TPU
+    and raises elsewhere; `interpret=True` runs the kernel body in the
+    Pallas interpreter (the correctness path on the CPU).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret, "flash_attention")
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

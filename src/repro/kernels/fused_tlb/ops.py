@@ -1,17 +1,23 @@
 """Dispatch wrapper for the fused TLB round kernel.
 
-Follows the `kernels/paged_attention/ops.py` backend-detection idiom:
 `interpret=None` means "lower for real" and is only legal on platforms
 with a Pallas lowering (TPU/GPU); anywhere else it raises instead of
 silently interpreting — interpret mode must be an explicit opt-in
 (`interpret=True`, or `tlb_backend="pallas-interpret"` /
 `REPRO_TLB_INTERPRET=1` one layer up in `sim/config.py`).
+
+The TPU compiler refuses this kernel today: the dynamic row gather
+`tags[set_ix]` in `kernel.py` fails Mosaic lowering with "Shape mismatch
+in input, indices and output" (tests/test_chip_compile.py keeps that
+refusal as a strict xfail).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+
+from repro.kernels import resolve_interpret
 
 from .kernel import fused_tlb_round
 
@@ -28,15 +34,10 @@ def fused_tlb_access(tags, asids, lru, vpn, asid, active, may_fill, time, *,
     hit/filled come back as int32 masks; counter arithmetic stays with
     the caller so both backends share it bit for bit.
     """
-    if interpret is None:
-        backend = jax.default_backend()
-        if backend not in PALLAS_PLATFORMS:
-            raise RuntimeError(
-                f"fused_tlb: no Pallas lowering for platform {backend!r}; "
-                "pass interpret=True (tlb_backend='pallas-interpret' or "
-                "REPRO_TLB_INTERPRET=1) to run the interpreter explicitly, "
-                "or use the 'xla' backend")
-        interpret = False
+    interpret = resolve_interpret(
+        interpret, "fused_tlb", PALLAS_PLATFORMS,
+        hint=" (tlb_backend='pallas-interpret' or REPRO_TLB_INTERPRET=1), "
+             "or use the 'xla' backend,")
     return fused_tlb_round(tags, asids, lru, vpn, asid, active, may_fill,
                            time, n_waves=n_waves, track_asids=track_asids,
                            interpret=interpret)

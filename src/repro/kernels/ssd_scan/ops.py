@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.ssd_scan.kernel import ssd_intra_chunk
 
 
@@ -17,9 +18,14 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256,
 
     x: (b, S, nh, hd); dt: (b, S, nh) positive; A: (nh,) negative;
     B, C: (b, S, ds).
+
+    `interpret=None` lowers the intra-chunk kernel for a TPU and raises
+    elsewhere; `interpret=True` runs the Pallas interpreter. The TPU
+    compiler refuses this kernel today: its (1, 1, chunk, hd) blocks break
+    the last-two-dims tiling rule (tests/test_chip_compile.py keeps that
+    refusal as a strict xfail).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "ssd_scan")
     b, S, nh, hd = x.shape
     ds = B.shape[-1]
     assert S % chunk == 0

@@ -63,3 +63,33 @@ def test_disabled_mask_is_single_queue():
     cls = ds.classify(st, jnp.asarray([0, 1]), jnp.asarray([True, False]),
                       mask_enabled=False)
     assert tuple(np.asarray(cls)) == (2, 2)
+
+
+def test_open_row_last_active_lane_wins():
+    """Several requests to one (channel, bank) in one call: the last
+    ACTIVE lane's row stays open, whatever order the backend applies a
+    scatter's duplicate updates in; a trailing inactive lane changes
+    nothing."""
+    st = _state()
+    z = jnp.zeros(4, jnp.int32)
+    st2, _ = ds.access(st, z, z, jnp.asarray([3, 7, 4, 9]), z,
+                       jnp.zeros(4, bool),
+                       jnp.asarray([True, True, False, False]),
+                       mask_enabled=True)
+    assert int(st2.open_row[0, 0]) == 7
+    assert (np.asarray(st2.open_row).ravel()[1:] == -1).all()
+
+
+def test_eq1_quota_matches_integer_reference():
+    """Eq. (1) in exact integer arithmetic, including quotients that are
+    exact integers (equal pressure splits thres_max evenly)."""
+    rng = np.random.default_rng(0)
+    cases = [([4, 4], [10, 10]), ([0, 0], [0, 0]), ([63, 1, 5], [960, 1, 7])]
+    cases += [(rng.integers(0, 65, 3).tolist(), rng.integers(0, 961, 3)
+               .tolist()) for _ in range(50)]
+    for conc, stalled in cases:
+        st = ds.update_pressure(ds.init(8, 8, len(conc)), jnp.asarray(conc),
+                                jnp.asarray(stalled))
+        w = [c * s for c, s in zip(conc, stalled)]
+        want = [max(500 * x // max(sum(w), 1), 1) for x in w]
+        assert np.asarray(ds.silver_quota(st, 500)).tolist() == want

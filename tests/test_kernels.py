@@ -109,3 +109,31 @@ def test_fused_tlb_raises_without_pallas_lowering():
     v = jnp.zeros((8,), jnp.int32)
     with pytest.raises(RuntimeError, match="no Pallas lowering"):
         fused_tlb_access(z, z, z, v, v, v, v, 0)
+
+
+def _tiny_kernel_call(kernel):
+    """One minimal call of each attention/SSD wrapper with interpret=None."""
+    f32 = jnp.float32
+    if kernel == "flash_attention":
+        x = jnp.zeros((1, 64, 1, 64), f32)
+        return flash_attention(x, x, x, block_q=64, block_k=64)
+    if kernel == "paged_attention":
+        pages = jnp.zeros((2, 8, 1, 64), f32)
+        return paged_attention(jnp.zeros((1, 1, 64), f32), pages, pages,
+                               jnp.zeros((1, 2), jnp.int32),
+                               jnp.ones((1,), jnp.int32))
+    x = jnp.zeros((1, 16, 1, 8), f32)
+    bc = jnp.zeros((1, 16, 8), f32)
+    return ssd_scan(x, jnp.ones((1, 16, 1), f32), -jnp.ones((1,), f32),
+                    bc, bc, chunk=16)
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "paged_attention",
+                                    "ssd_scan"])
+def test_kernels_raise_without_tpu_lowering(kernel):
+    """Same rule as fused_tlb: interpret=None lowers for real or raises;
+    interpret mode is never chosen quietly off the TPU."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("real Pallas lowering available")
+    with pytest.raises(RuntimeError, match="no Pallas lowering"):
+        _tiny_kernel_call(kernel)
