@@ -152,20 +152,12 @@ def access(state: DramState, channel, bank, row, app, is_tlb, active,
     latency = jnp.where(active, latency, 0)
 
     # ---- state updates ----
-    # open rows: last active request per (channel, bank) wins. The winner
-    # is picked explicitly (a scatter-max of lane order, which commutes)
-    # because XLA leaves the order of a scatter's duplicate updates to the
-    # backend: the CPU applies them in lane order, a TPU need not. Losing
-    # and inactive lanes are routed out of bounds and dropped — a masked
-    # write-back of the gathered value would let a trailing inactive lane
-    # clobber an earlier active lane's update with the stale row
-    n_cb = n_channels * n_banks
-    cb_lane = jnp.where(active, channel * n_banks + bank, n_cb)
-    lane = jnp.arange(N, dtype=jnp.int32)
-    last = jnp.full((n_cb,), -1, jnp.int32).at[cb_lane].max(lane, mode="drop")
-    win = active & (last[jnp.minimum(cb_lane, n_cb - 1)] == lane)
+    # open rows: last active request per (channel, bank) wins; inactive
+    # lanes are routed out of bounds and dropped — a masked write-back of
+    # the gathered value would let a trailing inactive lane clobber an
+    # earlier active lane's update with the stale cycle-start row
     new_open = state.open_row.at[
-        jnp.where(win, channel, n_channels), bank].set(row, mode="drop")
+        jnp.where(active, channel, n_channels), bank].set(row, mode="drop")
 
     # silver rotation: consume quota per wave (at most one rotation per
     # wave, like the sequential per-round calls); classification keeps the

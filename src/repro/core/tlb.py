@@ -271,13 +271,6 @@ def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
     flat = jnp.where(pre_hit, set_ix * n_ways + way,
                      jnp.where(winner, set_ix * n_ways + victim,
                                n_sets * n_ways))
-    # a slot written by several lanes (a probe-hit touch and a same-cycle
-    # fill of that way) keeps the last lane's write, picked explicitly:
-    # XLA leaves the order of a scatter's duplicate updates to the backend
-    n_slot = n_sets * n_ways
-    last = jnp.full((n_slot,), -1, jnp.int32).at[flat].max(order, mode="drop")
-    flat = jnp.where(last[jnp.minimum(flat, n_slot - 1)] == order, flat,
-                     n_slot)
     shape = state.tags.shape
     tags = state.tags.reshape(-1).at[flat].set(vpn, mode="drop").reshape(shape)
     lru = state.lru.reshape(-1).at[flat].set(time, mode="drop").reshape(shape)

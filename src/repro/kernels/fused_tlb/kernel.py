@@ -110,11 +110,6 @@ def _kernel(n_waves: int, track_asids: bool,
     flat = jnp.where(pre_hit, set_ix * n_ways + way,
                      jnp.where(winner, set_ix * n_ways + victim,
                                n_sets * n_ways))
-    # duplicate slots keep the last lane's write, picked explicitly
-    n_slot = n_sets * n_ways
-    last = jnp.full((n_slot,), -1, jnp.int32).at[flat].max(order, mode="drop")
-    flat = jnp.where(last[jnp.minimum(flat, n_slot - 1)] == order, flat,
-                     n_slot)
     tags = tags.reshape(-1).at[flat].set(vpn, mode="drop") \
         .reshape(n_sets, n_ways)
     lru = lru.reshape(-1).at[flat].set(t, mode="drop") \

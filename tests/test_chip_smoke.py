@@ -50,7 +50,6 @@ def test_smoke_fails_outside_the_repo(tmp_path):
     assert _result(p.stdout) is None, p.stdout[-2000:]
 
 
-@pytest.mark.slow
 @pytest.mark.multi_device
 @pytest.mark.parametrize("chips", [1, 4])
 def test_smoke_cpu_rehearsal(chips, tmp_path):

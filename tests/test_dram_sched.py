@@ -67,9 +67,9 @@ def test_disabled_mask_is_single_queue():
 
 def test_open_row_last_active_lane_wins():
     """Several requests to one (channel, bank) in one call: the last
-    ACTIVE lane's row stays open, whatever order the backend applies a
-    scatter's duplicate updates in; a trailing inactive lane changes
-    nothing."""
+    ACTIVE lane's row stays open (the scatter applies duplicate updates in
+    lane order, on the CPU and on a TPU v5e alike); a trailing inactive
+    lane changes nothing."""
     st = _state()
     z = jnp.zeros(4, jnp.int32)
     st2, _ = ds.access(st, z, z, jnp.asarray([3, 7, 4, 9]), z,
