@@ -5,7 +5,6 @@
   PYTHONPATH=src python -m benchmarks.run --quick         # subset (CI)
   PYTHONPATH=src python -m benchmarks.run --kernels       # kernel micro-bench
   PYTHONPATH=src python -m benchmarks.run --roofline      # dry-run summary
-  PYTHONPATH=src python -m benchmarks.run --perf          # steps/sec bench
   PYTHONPATH=src python -m benchmarks.run --list-designs  # design registry
 """
 from __future__ import annotations
@@ -157,7 +156,6 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--kernels", action="store_true")
     ap.add_argument("--roofline", action="store_true")
-    ap.add_argument("--perf", action="store_true")
     ap.add_argument("--list-designs", action="store_true")
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--force", action="store_true")
@@ -177,10 +175,6 @@ def main() -> None:
         return
     if args.roofline:
         run_roofline_summary()
-        return
-    if args.perf:
-        from benchmarks.perf import run_bench
-        run_bench()
         return
     if args.list_designs:
         list_designs()

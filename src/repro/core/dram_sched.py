@@ -69,6 +69,9 @@ def classify(state: DramState, app, is_tlb, mask_enabled):
     return jnp.where(mask_enabled, cls, jnp.int32(2))
 
 
+# names the DRAM round in the HLO metadata, nested under the calling
+# memsys stage's scope; see `sim.memsys.step`
+@jax.named_scope("mem.dram")
 def access(state: DramState, channel, bank, row, app, is_tlb, active,
            mask_enabled, thres_max=500,
            fr_fcfs: bool = True, waves: int = 1) -> Tuple[DramState, jax.Array]:

@@ -139,6 +139,9 @@ def fill_bank(state: TLBState, vpn, asid, do_fill, time) -> TLBState:
     return state._replace(tags=tags, asids=asids, lru=lru)
 
 
+# names the round in the HLO metadata (both backends), nested under the
+# calling memsys stage's scope; see `sim.memsys.step`
+@jax.named_scope("mem.fused_tlb")
 def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
                  n_waves: int = 1, track_asids: bool = True,
                  backend: str = "xla",

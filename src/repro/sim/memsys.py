@@ -760,29 +760,43 @@ def epoch_maintenance(cfg: SimConfig, dp: DesignParams, trans: TransState,
 def step(cfg: SimConfig, dp: DesignParams, params_mat,
          state: SimState) -> SimState:
     """One cycle. params_mat: (n_apps, N_FIELDS) int32 workload params;
-    dp: the design's traced knob plane (see `repro.core.design`)."""
+    dp: the design's traced knob plane (see `repro.core.design`).
+
+    Each stage runs under a `jax.named_scope` ("mem.<stage>"), which only
+    names its ops in the HLO metadata (`op_name`), so a profiler trace can
+    attribute device time to stages; the prefix keeps the scopes apart
+    from the Python function names JAX also writes there."""
     t = state.t + 1
-    sched = warp_sched(cfg, params_mat, state.stall_until, state.pos, t,
-                       asid_of_app=state.asid_of_app)
-    trans_st, probe = translation_probe(cfg, dp, state.trans, state.tokens,
-                                        sched, t)
-    dfront = datapath_front(cfg, params_mat, sched, t)
-    data_st, mem = shared_memory_access(
-        cfg, dp, state.data, sched.app, probe.walk_lines, probe.walk_go,
-        probe.walk_tags, dfront.lines, dfront.go_l2d, t)
-    trans_st, tout = translation_commit(cfg, trans_st, probe, mem, sched, t)
-    dout = _data_out(cfg, dfront, mem)
-
-    gap = params_mat[sched.app, FIELD["gap"]]
-    total_lat = tout.trans_lat + dout.data_lat + gap
-    stall_until, instr, pos = retire(
-        state.stall_until, state.instr, state.pos, sched, total_lat, gap, t)
-
-    tokens = tok_mod.record(state.tokens, sched.app, tout.l2_hit_eff,
-                            tout.l1_miss)
-    stats = accumulate_stats(state.stats, cfg.n_apps, sched, tout, dout, t)
-    tokens, data_st = epoch_maintenance(cfg, dp, state.trans, tokens,
-                                        data_st, t)
+    with jax.named_scope("mem.warp_sched"):
+        sched = warp_sched(cfg, params_mat, state.stall_until, state.pos, t,
+                           asid_of_app=state.asid_of_app)
+    with jax.named_scope("mem.translation_probe"):
+        trans_st, probe = translation_probe(cfg, dp, state.trans,
+                                            state.tokens, sched, t)
+    with jax.named_scope("mem.datapath_front"):
+        dfront = datapath_front(cfg, params_mat, sched, t)
+    with jax.named_scope("mem.shared_round"):
+        data_st, mem = shared_memory_access(
+            cfg, dp, state.data, sched.app, probe.walk_lines, probe.walk_go,
+            probe.walk_tags, dfront.lines, dfront.go_l2d, t)
+    with jax.named_scope("mem.translation_commit"):
+        trans_st, tout = translation_commit(cfg, trans_st, probe, mem, sched,
+                                            t)
+    with jax.named_scope("mem.retire"):
+        dout = _data_out(cfg, dfront, mem)
+        gap = params_mat[sched.app, FIELD["gap"]]
+        total_lat = tout.trans_lat + dout.data_lat + gap
+        stall_until, instr, pos = retire(
+            state.stall_until, state.instr, state.pos, sched, total_lat, gap,
+            t)
+        tokens = tok_mod.record(state.tokens, sched.app, tout.l2_hit_eff,
+                                tout.l1_miss)
+    with jax.named_scope("mem.stats"):
+        stats = accumulate_stats(state.stats, cfg.n_apps, sched, tout, dout,
+                                 t)
+    with jax.named_scope("mem.epoch"):
+        tokens, data_st = epoch_maintenance(cfg, dp, state.trans, tokens,
+                                            data_st, t)
 
     return SimState(t=t, stall_until=stall_until, instr=instr, pos=pos,
                     trans=trans_st, data=data_st, tokens=tokens, stats=stats,

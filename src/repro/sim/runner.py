@@ -23,6 +23,20 @@ rows along a vmapped grid axis — one compile and ONE device execution
 per (signature, n_apps) for a whole design x mix grid. The grid path
 is bit-for-bit identical to running the designs one by one (pinned by
 tests against the float-hex goldens).
+
+Profiler spans (`jax.profiler.TraceAnnotation`, recorded only while a
+`jax.profiler` trace is on, on the device trace's clock): the entry points
+`runner.run_mix`, `runner.run_grid`, `runner.predict_mixes` and
+`runner.sweep` each span their call, and inside them every device call is
+split into host phases at chunk level, never per row:
+
+* `runner.launch` -- building the workload matrices and stacking the
+  (DesignParams, workload) rows, padding and placing them on the row
+  sharding, and enqueueing the program;
+* `runner.fetch` -- the `jax.device_get` of the final state (it waits for
+  the device);
+* `runner.unpack` -- per-row state slicing and `_stats`, and the
+  assembly of predictions or results from them.
 """
 from __future__ import annotations
 
@@ -45,6 +59,8 @@ from repro.sim.memsys import (SimState, apply_membership_change, init_state,
 from repro.sim.workloads import app_matrix
 
 jax.config.update("jax_enable_x64", False)
+
+_span = jax.profiler.TraceAnnotation
 
 DesignLike = Union[str, Design]  # legacy DesignPoint also accepted
 
@@ -258,6 +274,7 @@ def _pad_rows(tree, multiple: int):
     return tree, rows
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.run_mix")
 def run_mix(design: DesignLike, benches: Sequence[Optional[str]],
             cycles: int = 60_000) -> Dict:
     """Co-run N apps under a design; returns per-app stats.
@@ -266,11 +283,15 @@ def run_mix(design: DesignLike, benches: Sequence[Optional[str]],
     emulation keeps the core split of the shared run but removes memory
     contention from the partner slots).
     """
-    cfg = SimConfig(n_apps=len(benches), sim_cycles=cycles,
-                    design=as_design(design))
-    pm = jnp.asarray(_mix_matrix(benches))
-    st = _compiled_run(cfg)(pm)
-    return _stats(cfg, st)
+    with _span("runner.launch"):
+        cfg = SimConfig(n_apps=len(benches), sim_cycles=cycles,
+                        design=as_design(design))
+        pm = jnp.asarray(_mix_matrix(benches))
+        st = _compiled_run(cfg)(pm)
+    with _span("runner.fetch"):
+        st = jax.device_get(st)
+    with _span("runner.unpack"):
+        return _stats(cfg, st)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -408,6 +429,7 @@ class FailureRecord:
             f"cycles={self.cycles}: {self.error_type}: {self.message}")
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.run_grid")
 def run_grid(designs: Sequence[DesignLike],
              bench_mixes: Sequence[Tuple[Optional[str], ...]],
              cycles: int = 60_000,
@@ -454,7 +476,8 @@ def run_grid(designs: Sequence[DesignLike],
         return []
     n = sizes.pop()
     M = len(bench_mixes)
-    pms = np.stack([_mix_matrix(m) for m in bench_mixes])
+    with _span("runner.launch"):
+        pms = np.stack([_mix_matrix(m) for m in bench_mixes])
     sharding = _row_sharding(devices) if devices and devices > 1 else None
     row_cap = max_rows * (devices if sharding is not None else 1)
     designs_per_call = max(row_cap // M, 1)
@@ -474,28 +497,32 @@ def run_grid(designs: Sequence[DesignLike],
         for lo in range(0, G, width):
             idxs = g_idxs[lo:lo + width]
             try:
-                dps = [design_params(ds[i]) for i in idxs]
-                # rows are design-major: row g*M + m = (design idxs[g],
-                # mix m)
-                dp_stack = jax.tree_util.tree_map(
-                    lambda *leaves: jnp.repeat(jnp.stack(leaves), M, axis=0),
-                    *dps)
-                pm_stack = jnp.asarray(np.tile(pms, (len(idxs), 1, 1)))
-                if sharding is not None:
-                    (dp_stack, pm_stack), _ = _pad_rows(
-                        (dp_stack, pm_stack), devices)
-                    dp_stack, pm_stack = jax.device_put(
-                        (dp_stack, pm_stack), sharding)
+                with _span("runner.launch"):
+                    dps = [design_params(ds[i]) for i in idxs]
+                    # rows are design-major: row g*M + m = (design idxs[g],
+                    # mix m)
+                    dp_stack = jax.tree_util.tree_map(
+                        lambda *leaves: jnp.repeat(jnp.stack(leaves), M,
+                                                   axis=0),
+                        *dps)
+                    pm_stack = jnp.asarray(np.tile(pms, (len(idxs), 1, 1)))
+                    if sharding is not None:
+                        (dp_stack, pm_stack), _ = _pad_rows(
+                            (dp_stack, pm_stack), devices)
+                        dp_stack, pm_stack = jax.device_put(
+                            (dp_stack, pm_stack), sharding)
+                    final = _compiled_grid_run(ccfg)(dp_stack, pm_stack)
                 # one bulk device->host transfer of the chunk's final
                 # state (padding rows ride along; the loop below never
                 # reads them)
-                final = jax.device_get(
-                    _compiled_grid_run(ccfg)(dp_stack, pm_stack))
-                for g, di in enumerate(idxs):
-                    for m in range(M):
-                        sub = jax.tree_util.tree_map(
-                            lambda x, r=g * M + m: x[r], final)
-                        out[di][m] = _stats(ccfg, sub)
+                with _span("runner.fetch"):
+                    final = jax.device_get(final)
+                with _span("runner.unpack"):
+                    for g, di in enumerate(idxs):
+                        for m in range(M):
+                            sub = jax.tree_util.tree_map(
+                                lambda x, r=g * M + m: x[r], final)
+                            out[di][m] = _stats(ccfg, sub)
             except Exception as e:  # noqa: BLE001 — fail-soft boundary
                 if not fail_soft:
                     raise
@@ -527,6 +554,8 @@ class MixPrediction:
     solo_ipc: Tuple[float, ...]
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="runner.predict_mixes")
 def predict_mixes(design: DesignLike,
                   mixes: Sequence[Sequence[str]],
                   cycles: int = 2_000,
@@ -574,32 +603,33 @@ def predict_mixes(design: DesignLike,
         target = -(-len(rows) // pad_rows) * pad_rows
         rows += [rows[-1]] * (target - len(rows))
     grid = run_grid([design], rows, cycles, fail_soft=fail_soft)[0]
-
-    solo_fail: Dict[str, FailureRecord] = {}
-    for b, s in zip(need_solo, grid[len(mixes):len(mixes) + len(need_solo)]):
-        if isinstance(s, FailureRecord):
-            solo_fail[b] = s
-        else:
-            solo_cache[b] = float(s["ipc"][0])
-    out: List[Union[MixPrediction, FailureRecord]] = []
-    for m, s in zip(mixes, grid[:len(mixes)]):
-        if isinstance(s, FailureRecord):
-            out.append(s)
-            continue
-        bad = next((solo_fail[b] for b in m if b in solo_fail), None)
-        if bad is not None:
-            out.append(bad)
-            continue
-        solo = tuple(solo_cache[b] for b in m)
-        ipc = tuple(float(s["ipc"][i]) for i in range(len(m)))
-        slow = tuple(a / max(i, 1e-9) for a, i in zip(solo, ipc))
-        out.append(MixPrediction(
-            benches=m,
-            weighted_speedup=float(sum(i / max(a, 1e-9)
-                                       for i, a in zip(ipc, solo))),
-            max_slowdown=float(max(slow)),
-            slowdown=slow, ipc=ipc, solo_ipc=solo))
-    return out
+    with _span("runner.unpack"):
+        solo_fail: Dict[str, FailureRecord] = {}
+        solos = grid[len(mixes):len(mixes) + len(need_solo)]
+        for b, s in zip(need_solo, solos):
+            if isinstance(s, FailureRecord):
+                solo_fail[b] = s
+            else:
+                solo_cache[b] = float(s["ipc"][0])
+        out: List[Union[MixPrediction, FailureRecord]] = []
+        for m, s in zip(mixes, grid[:len(mixes)]):
+            if isinstance(s, FailureRecord):
+                out.append(s)
+                continue
+            bad = next((solo_fail[b] for b in m if b in solo_fail), None)
+            if bad is not None:
+                out.append(bad)
+                continue
+            solo = tuple(solo_cache[b] for b in m)
+            ipc = tuple(float(s["ipc"][i]) for i in range(len(m)))
+            slow = tuple(a / max(i, 1e-9) for a, i in zip(solo, ipc))
+            out.append(MixPrediction(
+                benches=m,
+                weighted_speedup=float(sum(i / max(a, 1e-9)
+                                           for i, a in zip(ipc, solo))),
+                max_slowdown=float(max(slow)),
+                slowdown=slow, ipc=ipc, solo_ipc=solo))
+        return out
 
 
 def run_pair(design: DesignLike, bench_a: str, bench_b: str,
@@ -870,6 +900,7 @@ class Experiment:
                                 plans, stats_by_n)
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.sweep")
 def sweep(designs: Sequence[DesignLike],
           mixes: Sequence, cycles: int = 60_000,
           solo_baselines: bool = True,
@@ -915,10 +946,11 @@ def sweep(designs: Sequence[DesignLike],
                          fail_soft=fail_soft)
              for n, plan in plans.items()}        # stats[n][design][row]
     out: Dict[str, Union[ExperimentResult, FailureRecord]] = {}
-    for i, d in enumerate(ds):
-        rows_by_n = {n: stats[n][i] for n in plans}
-        failed = [s for rows in rows_by_n.values() for s in rows
-                  if isinstance(s, FailureRecord)]
-        out[d.name] = failed[0] if failed else _assemble_result(
-            d, cycles, len(norm), plans, rows_by_n)
+    with _span("runner.unpack"):
+        for i, d in enumerate(ds):
+            rows_by_n = {n: stats[n][i] for n in plans}
+            failed = [s for rows in rows_by_n.values() for s in rows
+                      if isinstance(s, FailureRecord)]
+            out[d.name] = failed[0] if failed else _assemble_result(
+                d, cycles, len(norm), plans, rows_by_n)
     return out
