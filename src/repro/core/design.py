@@ -37,6 +37,7 @@ from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # translation organizations (paper Fig. 2a/2b + the ideal upper bound)
 TRANSLATION_KINDS = ("ideal", "pwc", "shared_l2_tlb", "walk_only")
@@ -296,20 +297,28 @@ class DesignParams(NamedTuple):
     static_part: jax.Array      # () bool: static L2$/DRAM partitioning
 
 
-def design_params(d) -> DesignParams:
-    """Pack a design's dynamic knobs into the traced `DesignParams` plane."""
+def host_design_params(d) -> DesignParams:
+    """`design_params` as 0-d numpy arrays on the host, with the same
+    (non-weak) dtypes: rows stacked from them with numpy feed the
+    compiled grid the avals of stacked `design_params`, so it never
+    retraces."""
     d = as_design(d)
     return DesignParams(
-        use_l2_tlb=jnp.asarray(d.translation.kind == "shared_l2_tlb", bool),
-        use_pwc=jnp.asarray(d.translation.kind == "pwc", bool),
-        tokens_on=jnp.asarray(d.tokens.enabled, bool),
-        initial_frac=jnp.asarray(d.tokens.initial_frac, jnp.float32),
-        step_frac=jnp.asarray(d.tokens.step_frac, jnp.float32),
-        bypass_on=jnp.asarray(d.bypass.enabled, bool),
-        dram_on=jnp.asarray(d.dram.enabled, bool),
-        thres_max=jnp.asarray(d.dram.thres_max, jnp.int32),
-        static_part=jnp.asarray(d.partition.kind == "static", bool),
+        use_l2_tlb=np.asarray(d.translation.kind == "shared_l2_tlb", bool),
+        use_pwc=np.asarray(d.translation.kind == "pwc", bool),
+        tokens_on=np.asarray(d.tokens.enabled, bool),
+        initial_frac=np.asarray(d.tokens.initial_frac, np.float32),
+        step_frac=np.asarray(d.tokens.step_frac, np.float32),
+        bypass_on=np.asarray(d.bypass.enabled, bool),
+        dram_on=np.asarray(d.dram.enabled, bool),
+        thres_max=np.asarray(d.dram.thres_max, np.int32),
+        static_part=np.asarray(d.partition.kind == "static", bool),
     )
+
+
+def design_params(d) -> DesignParams:
+    """Pack a design's dynamic knobs into the traced `DesignParams` plane."""
+    return jax.tree_util.tree_map(jnp.asarray, host_design_params(d))
 
 
 def from_legacy(dp) -> Design:

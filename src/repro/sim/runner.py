@@ -31,12 +31,15 @@ Profiler spans (`jax.profiler.TraceAnnotation`, recorded only while a
 split into host phases at chunk level, never per row:
 
 * `runner.launch` -- building the workload matrices and stacking the
-  (DesignParams, workload) rows, padding and placing them on the row
-  sharding, and enqueueing the program;
-* `runner.fetch` -- the `jax.device_get` of the final state (it waits for
+  (DesignParams, workload) rows with numpy on the host, padding them and
+  placing them on the device (on the row sharding) in one transfer, and
+  enqueueing the program;
+* `runner.fetch` -- the `jax.device_get` of the leaves of the final state
+  that `_stats` reads, or of the whole state under audit (it waits for
   the device);
-* `runner.unpack` -- per-row state slicing and `_stats`, and the
-  assembly of predictions or results from them.
+* `runner.unpack` -- one `_stats_rows` pass over all of a chunk's rows,
+  each row's dict from `_stats`, and the assembly of predictions or
+  results from them.
 """
 from __future__ import annotations
 
@@ -51,7 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.design import (Design, as_design, canonical_design,
-                               design_params, static_signature)
+                               design_params, host_design_params,
+                               static_signature)
 from repro.sim import faults as faults_mod
 from repro.sim.config import SimConfig
 from repro.sim.memsys import (SimState, apply_membership_change, init_state,
@@ -68,6 +72,10 @@ DesignLike = Union[str, Design]  # legacy DesignPoint also accepted
 # (once per jit/vmap wrapper; re-executions hit the cache and do not
 # bump it) — tests assert "one trace per signature group" against this
 TRACE_COUNT = 0
+
+# bytes every `runner.fetch` has copied to the host — tests read which
+# fetch ran (the leaves `_stats` reads, or the whole state under audit)
+FETCHED_BYTES = 0
 
 
 def _canonical(cfg: SimConfig) -> SimConfig:
@@ -178,27 +186,65 @@ def _audit_enabled(audit: Optional[bool]) -> bool:
     return os.environ.get("REPRO_AUDIT", "") in ("1", "true", "yes")
 
 
-def _stats(cfg: SimConfig, st: SimState,
-           audit: Optional[bool] = None) -> Dict[str, np.ndarray]:
-    # one bulk transfer for the whole state tree (no-op on numpy trees,
-    # e.g. the per-mix slices run_batch hands over)
-    st = jax.device_get(st)
-    if _audit_enabled(audit):
+def _stats_leaves(st: SimState) -> SimState:
+    """`st` with only the leaves `_stats` reads (`t`, `instr`, the
+    `stats` planes and `tokens.tokens`), None in place of the rest."""
+    return SimState(
+        t=st.t, stall_until=None, instr=st.instr, pos=None, trans=None,
+        data=None, stats=st.stats, asid_of_app=None,
+        tokens=st.tokens._replace(**{f: None for f in st.tokens._fields
+                                     if f != "tokens"}))
+
+
+def _fetch(st: SimState, audit: bool) -> SimState:
+    """Copy a final state to the host: the leaves `_stats` reads, or the
+    whole state when auditing (`sim.audit.check_state` reads it all)."""
+    global FETCHED_BYTES
+    host = jax.device_get(st if audit else _stats_leaves(st))
+    FETCHED_BYTES += sum(np.asarray(x).nbytes
+                         for x in jax.tree_util.tree_leaves(host))
+    return host
+
+
+class _StatsRow(NamedTuple):
+    """Row `r` of a stack of final states whose statistics `_stats_rows`
+    has already computed (and audited)."""
+    stats: Dict[str, np.ndarray]
+    r: int
+
+
+def _stats_rows(cfg: SimConfig, st: SimState,
+                audit: bool) -> Dict[str, np.ndarray]:
+    """Per-app statistics of a host stack of final states, in one pass
+    over its leading row axis: every value gains the row axis ("cycles"
+    too). Audits every row first when `audit` holds. Raises for the
+    first row that simulated no cycles or has non-finite IPC."""
+    if audit:
         from repro.sim.audit import check_state
-        check_state(cfg, st)
+        for r in range(len(st.t)):
+            check_state(cfg, jax.tree_util.tree_map(lambda x, r=r: x[r], st))
     na = cfg.n_apps
-    warp_app = np.repeat(np.asarray(cfg.app_of_core), cfg.warps_per_core)
-    t = float(st.t)
-    if not t > 0:
+    t = np.asarray(st.t, np.float64)
+    instr = np.asarray(st.instr)
+    R = len(t)
+    if not np.all(t > 0):
+        r = int(np.argmin(t > 0))
         raise ZeroCycleError(
-            f"cannot derive per-app IPC from a {t:.0f}-cycle run "
+            f"cannot derive per-app IPC from a {t[r]:.0f}-cycle run "
             f"(design={cfg.design.name!r}): IPC = instructions / cycles "
             "would be NaN/inf and silently poison weighted_speedup / "
             "unfairness downstream — run with cycles >= 1")
-    ipc = np.bincount(warp_app, weights=st.instr, minlength=na) / t
-    if not np.all(np.isfinite(ipc)):
+    # one bincount over (row, app) bins: each bin sums its warps in warp
+    # order, exactly as a per-row bincount does
+    warp_app = np.repeat(np.asarray(cfg.app_of_core), cfg.warps_per_core)
+    bins = (np.arange(R)[:, None] * na + warp_app).ravel()
+    ipc = np.bincount(bins, weights=instr.ravel(),
+                      minlength=R * na).reshape(R, na) / t[:, None]
+    finite = np.isfinite(ipc).all(axis=1)
+    if not finite.all():
+        r = int(np.argmin(finite))
         raise NonFiniteStatsError(
-            f"non-finite per-app IPC {ipc} after {t:.0f} cycles "
+            f"non-finite per-app IPC {ipc[r]} after {t[r]:.0f} cycles "
             f"(design={cfg.design.name!r}): the retired-instruction "
             "counters are corrupt (overflow or injected fault); refusing "
             "to propagate NaN into weighted_speedup / unfairness")
@@ -228,8 +274,31 @@ def _stats(cfg: SimConfig, st: SimState,
         "l2c_data_hit_rate": (g(s.s_l2c_data_hit)
                               / np.maximum(g(s.s_l2c_data_probe), 1)),
         "tokens": np.asarray(st.tokens.tokens),
-        "cycles": float(st.t),
+        "cycles": t,
     }
+
+
+def _stats(cfg: SimConfig, st,
+           audit: Optional[bool] = None) -> Dict[str, np.ndarray]:
+    """Per-app statistics of one final state, the dict of one answer.
+
+    `st` is a host tree (`_fetch` copies one; device leaves are read one
+    at a time), which is `_stats_rows` on a row axis of length one, or a
+    `_StatsRow` of a stack whose rows `_stats_rows` did at once. Every
+    answer the runner returns is made here, once."""
+    if not isinstance(st, _StatsRow):
+        one = jax.tree_util.tree_map(lambda x: np.asarray(x)[None], st)
+        st = _StatsRow(_stats_rows(cfg, one, _audit_enabled(audit)), 0)
+    return {k: float(v[st.r]) if k == "cycles" else v[st.r]
+            for k, v in st.stats.items()}
+
+
+def _row_stats(cfg: SimConfig, st: SimState, audit: bool) -> List[Dict]:
+    """`_stats` of every row of a host stack of final states, from one
+    `_stats_rows` pass over the stack."""
+    rows = _stats_rows(cfg, st, audit)
+    return [_stats(cfg, _StatsRow(rows, r))
+            for r in range(len(rows["cycles"]))]
 
 
 def _mix_matrix(benches: Sequence[Optional[str]]) -> np.ndarray:
@@ -260,8 +329,8 @@ def _row_sharding(devices: int):
 
 
 def _pad_rows(tree, multiple: int):
-    """Pad every leaf's leading axis up to a multiple of `multiple` by
-    repeating the first rows; returns (padded_tree, real_row_count).
+    """Pad every (host) leaf's leading axis up to a multiple of `multiple`
+    by repeating the first rows; returns (padded_tree, real_row_count).
 
     Repeated leading rows keep every row a valid simulation (no NaN/zero
     design surprises); callers slice results back to the real count.
@@ -270,8 +339,19 @@ def _pad_rows(tree, multiple: int):
     pad = (-rows) % multiple
     if pad:
         tree = jax.tree_util.tree_map(
-            lambda x: jnp.concatenate([x, x[:pad]], axis=0), tree)
+            lambda x: np.concatenate([x, x[:pad]], axis=0), tree)
     return tree, rows
+
+
+def _grid_rows(designs: Sequence[Design], pms: np.ndarray):
+    """One chunk's stacked (DesignParams, params_mat) rows, built with
+    numpy on the host. Rows are design-major: row g*M + m is
+    (designs[g], mix m) for the M mixes of `pms`."""
+    M = len(pms)
+    dp = jax.tree_util.tree_map(
+        lambda *leaves: np.repeat(np.stack(leaves), M, axis=0),
+        *[host_design_params(d) for d in designs])
+    return dp, np.tile(pms, (len(designs), 1, 1))
 
 
 @functools.partial(jax.profiler.annotate_function, name="runner.run_mix")
@@ -283,15 +363,16 @@ def run_mix(design: DesignLike, benches: Sequence[Optional[str]],
     emulation keeps the core split of the shared run but removes memory
     contention from the partner slots).
     """
+    aud = _audit_enabled(None)
     with _span("runner.launch"):
         cfg = SimConfig(n_apps=len(benches), sim_cycles=cycles,
                         design=as_design(design))
         pm = jnp.asarray(_mix_matrix(benches))
         st = _compiled_run(cfg)(pm)
     with _span("runner.fetch"):
-        st = jax.device_get(st)
+        st = _fetch(st, aud)
     with _span("runner.unpack"):
-        return _stats(cfg, st)
+        return _stats(cfg, st, aud)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -364,6 +445,7 @@ def run_trace(design: DesignLike,
     ops = (faults_mod.plan_operands(fault_plan, cfg, K) if fault_plan
            else faults_mod.empty_operands(cfg, K))
     seg_run = _compiled_seg_run(ccfg)
+    aud = _audit_enabled(audit)
 
     state = init_state(ccfg, dp)
     snaps: List[Dict] = []
@@ -376,7 +458,7 @@ def run_trace(design: DesignLike,
         fops = jax.tree_util.tree_map(lambda x, k=k: x[k], ops)
         state = seg_run(dp, pm, state, jnp.asarray(change), fops)
         if collect_segments or k == K - 1:
-            snaps.append(_stats(cfg, state, audit=audit))
+            snaps.append(_stats(cfg, _fetch(state, aud), aud))
         prev = benches
     return TraceResult(
         design=cfg.design, schedule=tuple(schedule), seg_cycles=seg_cycles,
@@ -395,14 +477,9 @@ def run_batch(design: DesignLike,
     cfg = SimConfig(n_apps=sizes.pop(), sim_cycles=cycles,
                     design=as_design(design))
     pm = jnp.asarray(np.stack([_mix_matrix(m) for m in bench_mixes]))
-    # one bulk device->host transfer of the whole batched final state,
-    # then cheap numpy views per mix (was B per-mix tree transfers)
-    final = jax.device_get(_compiled_batch_run(cfg)(pm))
-    out = []
-    for i in range(len(bench_mixes)):
-        sub = jax.tree_util.tree_map(lambda x: x[i], final)
-        out.append(_stats(cfg, sub))
-    return out
+    aud = _audit_enabled(None)
+    # one bulk device->host transfer and one stats pass over every mix
+    return _row_stats(cfg, _fetch(_compiled_batch_run(cfg)(pm), aud), aud)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -467,6 +544,12 @@ def run_grid(designs: Sequence[DesignLike],
     every cell the chunk covered, and CONTINUES with the remaining
     chunks and signature groups — one poisoned design cannot abort the
     sweep. Default False preserves raise-on-first-error semantics.
+
+    Each chunk's host work is array-at-a-time: its rows are stacked with
+    numpy and placed in one transfer, only the leaves `_stats` reads are
+    fetched, and one `_stats_rows` pass covers every row. Under env
+    `REPRO_AUDIT` the whole state is fetched instead, and
+    `sim.audit.check_state` runs on every real row.
     """
     ds = [as_design(d) for d in designs]
     sizes = {len(m) for m in bench_mixes}
@@ -476,6 +559,7 @@ def run_grid(designs: Sequence[DesignLike],
         return []
     n = sizes.pop()
     M = len(bench_mixes)
+    aud = _audit_enabled(None)
     with _span("runner.launch"):
         pms = np.stack([_mix_matrix(m) for m in bench_mixes])
     sharding = _row_sharding(devices) if devices and devices > 1 else None
@@ -496,33 +580,23 @@ def run_grid(designs: Sequence[DesignLike],
             w for w in range(1, designs_per_call + 1) if G % w == 0)
         for lo in range(0, G, width):
             idxs = g_idxs[lo:lo + width]
+            real = len(idxs) * M
             try:
                 with _span("runner.launch"):
-                    dps = [design_params(ds[i]) for i in idxs]
-                    # rows are design-major: row g*M + m = (design idxs[g],
-                    # mix m)
-                    dp_stack = jax.tree_util.tree_map(
-                        lambda *leaves: jnp.repeat(jnp.stack(leaves), M,
-                                                   axis=0),
-                        *dps)
-                    pm_stack = jnp.asarray(np.tile(pms, (len(idxs), 1, 1)))
+                    rows = _grid_rows([ds[i] for i in idxs], pms)
                     if sharding is not None:
-                        (dp_stack, pm_stack), _ = _pad_rows(
-                            (dp_stack, pm_stack), devices)
-                        dp_stack, pm_stack = jax.device_put(
-                            (dp_stack, pm_stack), sharding)
-                    final = _compiled_grid_run(ccfg)(dp_stack, pm_stack)
+                        rows, _ = _pad_rows(rows, devices)
+                    # one host->device transfer of the chunk's rows
+                    final = _compiled_grid_run(ccfg)(
+                        *jax.device_put(rows, sharding))
                 # one bulk device->host transfer of the chunk's final
-                # state (padding rows ride along; the loop below never
-                # reads them)
+                # state (padding rows ride along and are dropped here)
                 with _span("runner.fetch"):
-                    final = jax.device_get(final)
+                    final = _fetch(final, aud)
                 with _span("runner.unpack"):
-                    for g, di in enumerate(idxs):
-                        for m in range(M):
-                            sub = jax.tree_util.tree_map(
-                                lambda x, r=g * M + m: x[r], final)
-                            out[di][m] = _stats(ccfg, sub)
+                    final = jax.tree_util.tree_map(lambda x: x[:real], final)
+                    for r, s in enumerate(_row_stats(ccfg, final, aud)):
+                        out[idxs[r // M]][r % M] = s
             except Exception as e:  # noqa: BLE001 — fail-soft boundary
                 if not fail_soft:
                     raise

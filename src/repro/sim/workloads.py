@@ -10,6 +10,7 @@ does. Streams mix: sequential striding (spatial locality), a hot page set
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Tuple
 
@@ -133,10 +134,18 @@ def idle_app() -> AppParams:
 IDLE_ROW = idle_app().as_array()
 
 
+@functools.lru_cache(maxsize=None)
+def app_row(name: Optional[str]) -> np.ndarray:
+    """(N_FIELDS,) int32 row of `make_app(name)` (the idle row for None),
+    built once per name; read-only, so no caller can change the cache."""
+    row = make_app(name).as_array() if name is not None else IDLE_ROW.copy()
+    row.setflags(write=False)
+    return row
+
+
 def app_matrix(names) -> np.ndarray:
     """(n_apps, N_FIELDS) int32 parameter matrix. None entries -> idle app."""
-    return np.stack([make_app(n).as_array() if n is not None else IDLE_ROW
-                     for n in names])
+    return np.stack([app_row(n) for n in names])
 
 
 def gen_vpn(params_row, app_id, warp_id, pos, t):
