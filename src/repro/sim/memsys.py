@@ -857,54 +857,57 @@ def apply_membership_change(cfg: SimConfig, dp: DesignParams,
     Everything is a `jnp.where` on the change mask (plus one `change.any()`
     select for the PWC), so an all-False mask returns `state` bitwise
     unchanged — which is what makes constant-membership segmented runs
-    float-hex identical to monolithic ones.
+    float-hex identical to monolithic ones. The teardown runs under
+    `jax.named_scope("mem.membership")`, which names its ops in the HLO
+    metadata, so a profiler trace can attribute its device time.
     """
-    na = cfg.n_apps
-    change = jnp.asarray(change, bool)
-    any_c = change.any()
+    with jax.named_scope("mem.membership"):
+        na = cfg.n_apps
+        change = jnp.asarray(change, bool)
+        any_c = change.any()
 
-    trans = state.trans
-    pwc = trans.pwc._replace(
-        tags=jnp.where(any_c, jnp.full_like(trans.pwc.tags, -1),
-                       trans.pwc.tags))
-    walk_slot = trans.walk[:, WASID] % na
-    walk_kill = (trans.walk[:, WASID] >= 0) & change[walk_slot]
-    empty_row = jnp.asarray([-1, -1, 0, 0], jnp.int32)
-    walk = jnp.where(walk_kill[:, None], empty_row[None, :], trans.walk)
-    trans = trans._replace(
-        l1=_flush_slots(trans.l1, change, na),
-        l2tlb=_flush_slots(trans.l2tlb, change, na),
-        bypass_tlb=_flush_slots(trans.bypass_tlb, change, na),
-        pwc=pwc, walk=walk)
+        trans = state.trans
+        pwc = trans.pwc._replace(
+            tags=jnp.where(any_c, jnp.full_like(trans.pwc.tags, -1),
+                           trans.pwc.tags))
+        walk_slot = trans.walk[:, WASID] % na
+        walk_kill = (trans.walk[:, WASID] >= 0) & change[walk_slot]
+        empty_row = jnp.asarray([-1, -1, 0, 0], jnp.int32)
+        walk = jnp.where(walk_kill[:, None], empty_row[None, :], trans.walk)
+        trans = trans._replace(
+            l1=_flush_slots(trans.l1, change, na),
+            l2tlb=_flush_slots(trans.l2tlb, change, na),
+            bypass_tlb=_flush_slots(trans.bypass_tlb, change, na),
+            pwc=pwc, walk=walk)
 
-    fresh_tok = tok_mod.init(na, jnp.asarray(cfg.warps_per_app, jnp.int32),
-                             dp.initial_frac)
-    tok = state.tokens
-    tok = tok._replace(
-        tokens=jnp.where(change, fresh_tok.tokens, tok.tokens),
-        direction=jnp.where(change, fresh_tok.direction, tok.direction),
-        prev_miss_rate=jnp.where(change, fresh_tok.prev_miss_rate,
-                                 tok.prev_miss_rate),
-        epoch_hits=jnp.where(change, 0, tok.epoch_hits),
-        epoch_misses=jnp.where(change, 0, tok.epoch_misses))
+        fresh_tok = tok_mod.init(
+            na, jnp.asarray(cfg.warps_per_app, jnp.int32), dp.initial_frac)
+        tok = state.tokens
+        tok = tok._replace(
+            tokens=jnp.where(change, fresh_tok.tokens, tok.tokens),
+            direction=jnp.where(change, fresh_tok.direction, tok.direction),
+            prev_miss_rate=jnp.where(change, fresh_tok.prev_miss_rate,
+                                     tok.prev_miss_rate),
+            epoch_hits=jnp.where(change, 0, tok.epoch_hits),
+            epoch_misses=jnp.where(change, 0, tok.epoch_misses))
 
-    dram = state.data.dram
-    dram = dram._replace(
-        conc_walks=jnp.where(change, 0, dram.conc_walks),
-        warps_stalled=jnp.where(change, 0, dram.warps_stalled))
+        dram = state.data.dram
+        dram = dram._replace(
+            conc_walks=jnp.where(change, 0, dram.conc_walks),
+            warps_stalled=jnp.where(change, 0, dram.warps_stalled))
 
-    warp_change = change[jnp.repeat(
-        jnp.asarray(cfg.app_of_core, jnp.int32), cfg.warps_per_core)]
-    stall_until = jnp.where(warp_change, state.t, state.stall_until)
-    instr = jnp.where(warp_change, 0.0, state.instr)
-    pos = jnp.where(warp_change, 0, state.pos)
+        warp_change = change[jnp.repeat(
+            jnp.asarray(cfg.app_of_core, jnp.int32), cfg.warps_per_core)]
+        stall_until = jnp.where(warp_change, state.t, state.stall_until)
+        instr = jnp.where(warp_change, 0.0, state.instr)
+        pos = jnp.where(warp_change, 0, state.pos)
 
-    stats = state.stats._replace(
-        ints=jnp.where(change[:, None], 0, state.stats.ints),
-        floats=jnp.where(change[:, None], 0.0, state.stats.floats))
+        stats = state.stats._replace(
+            ints=jnp.where(change[:, None], 0, state.stats.ints),
+            floats=jnp.where(change[:, None], 0.0, state.stats.floats))
 
-    return state._replace(
-        stall_until=stall_until, instr=instr, pos=pos, trans=trans,
-        data=state.data._replace(dram=dram), tokens=tok, stats=stats,
-        asid_of_app=jnp.where(change, state.asid_of_app + na,
-                              state.asid_of_app))
+        return state._replace(
+            stall_until=stall_until, instr=instr, pos=pos, trans=trans,
+            data=state.data._replace(dram=dram), tokens=tok, stats=stats,
+            asid_of_app=jnp.where(change, state.asid_of_app + na,
+                                  state.asid_of_app))

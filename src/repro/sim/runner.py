@@ -26,20 +26,27 @@ tests against the float-hex goldens).
 
 Profiler spans (`jax.profiler.TraceAnnotation`, recorded only while a
 `jax.profiler` trace is on, on the device trace's clock): the entry points
-`runner.run_mix`, `runner.run_grid`, `runner.predict_mixes` and
-`runner.sweep` each span their call, and inside them every device call is
-split into host phases at chunk level, never per row:
+`runner.run_mix`, `runner.run_grid`, `runner.predict_mixes`,
+`runner.sweep` and `runner.run_trace` each span their call, and inside
+them every device call is split into host phases at chunk (or segment)
+level, never per row:
 
 * `runner.launch` -- building the workload matrices and stacking the
   (DesignParams, workload) rows with numpy on the host, padding them and
   placing them on the device (on the row sharding) in one transfer, and
-  enqueueing the program;
+  enqueueing the program; in `run_trace`, a segment's membership matrix,
+  change mask and slice of the fault operands, and its enqueue;
 * `runner.fetch` -- the `jax.device_get` of the leaves of the final state
   that `_stats` reads, or of the whole state under audit (it waits for
   the device);
 * `runner.unpack` -- one `_stats_rows` pass over all of a chunk's rows,
   each row's dict from `_stats`, and the assembly of predictions or
   results from them.
+
+`run_trace` also wraps each segment boundary in `runner.boundary`:
+segment k's fetch and unpack, then segment k+1's launch -- the host work
+the device waits on between two segments (the span opens when the host
+starts waiting for segment k).
 """
 from __future__ import annotations
 
@@ -385,7 +392,9 @@ class TraceResult:
     is the cumulative stats snapshot after segment k. Counters of a slot
     reset when its membership changes (the arriving app starts cold), so
     a churned slot's numbers read "since its last arrival"; `ipc` always
-    divides by the TOTAL elapsed cycles.
+    divides by the TOTAL elapsed cycles, and the shared L2$ hit rates
+    (`l2c_tlb_hit_rate`, `l2c_data_hit_rate`) are not per slot and count
+    from cycle 0.
     """
     design: Design
     schedule: Tuple[Tuple[Optional[str], ...], ...]
@@ -398,6 +407,7 @@ class TraceResult:
         return self.stats[key]
 
 
+@functools.partial(jax.profiler.annotate_function, name="runner.run_trace")
 def run_trace(design: DesignLike,
               schedule: Sequence[Tuple[Optional[str], ...]],
               seg_cycles: int = 2_000,
@@ -447,19 +457,30 @@ def run_trace(design: DesignLike,
     seg_run = _compiled_seg_run(ccfg)
     aud = _audit_enabled(audit)
 
-    state = init_state(ccfg, dp)
     snaps: List[Dict] = []
-    prev: Optional[Tuple[Optional[str], ...]] = None
-    for k, benches in enumerate(schedule):
-        pm = jnp.asarray(_mix_matrix(benches))
-        # segment 0's membership is the cold init itself: no teardown
-        change = np.zeros(n, bool) if prev is None else np.array(
-            [a != b for a, b in zip(prev, benches)])
-        fops = jax.tree_util.tree_map(lambda x, k=k: x[k], ops)
-        state = seg_run(dp, pm, state, jnp.asarray(change), fops)
+
+    def launch(k, state):
+        with _span("runner.launch"):
+            pm = jnp.asarray(_mix_matrix(schedule[k]))
+            # segment 0's membership is the cold init itself: no teardown
+            change = np.zeros(n, bool) if k == 0 else np.array(
+                [a != b for a, b in zip(schedule[k - 1], schedule[k])])
+            fops = jax.tree_util.tree_map(lambda x: x[k], ops)
+            return seg_run(dp, pm, state, jnp.asarray(change), fops)
+
+    def collect(k, state):
         if collect_segments or k == K - 1:
-            snaps.append(_stats(cfg, _fetch(state, aud), aud))
-        prev = benches
+            with _span("runner.fetch"):
+                host = _fetch(state, aud)
+            with _span("runner.unpack"):
+                snaps.append(_stats(cfg, host, aud))
+
+    state = launch(0, init_state(ccfg, dp))
+    for k in range(1, K):
+        with _span("runner.boundary"):
+            collect(k - 1, state)
+            state = launch(k, state)
+    collect(K - 1, state)
     return TraceResult(
         design=cfg.design, schedule=tuple(schedule), seg_cycles=seg_cycles,
         stats=snaps[-1], segments=tuple(snaps) if collect_segments else (),

@@ -83,3 +83,46 @@ def test_run_mix_and_sweep_spans(tmp_path):
     assert names.count("runner.launch") == 3
     assert names.count("runner.fetch") == 2
     assert names.count("runner.unpack") == 3
+
+
+def test_membership_scope_is_in_the_segment_program():
+    from repro.sim import faults
+    from repro.sim.memsys import init_state
+    cfg = runner._canonical(SimConfig(n_cores=4, warps_per_core=4, n_apps=2,
+                                      sim_cycles=4, design=design("mask")))
+    dp = design_params(design("mask"))
+    pm = jnp.asarray(runner._mix_matrix(["3DS", "BLK"]))
+    fops = jax.tree_util.tree_map(lambda x: x[0],
+                                  faults.empty_operands(cfg, 1))
+    text = runner._compiled_seg_run(cfg).lower(
+        dp, pm, init_state(cfg, dp), jnp.zeros(2, bool), fops).as_text(
+        debug_info=True)
+    segments = {seg for loc in text.split('loc("')[1:]
+                for seg in loc.split('"')[0].split("/")}
+    assert "mem.membership" in segments and set(STAGES) <= segments
+
+
+def test_run_trace_spans_one_boundary_per_boundary(tmp_path):
+    schedule = [("3DS", "BLK"), ("3DS", None), ("MUM", None)]
+    runner.run_trace("mask", schedule, seg_cycles=4)   # compile
+    with jax.profiler.trace(str(tmp_path)):
+        runner.run_trace("mask", schedule, seg_cycles=4)
+    events = _host_spans(tmp_path)
+    by = {n: sorted((s, e) for m, s, e in events if m == n) for n in (
+        "runner.run_trace", "runner.boundary", "runner.launch",
+        "runner.fetch", "runner.unpack")}
+    (outer,) = by["runner.run_trace"]
+    within = lambda a, b: b[0] <= a[0] and a[1] <= b[1]  # noqa: E731
+    # a launch, fetch and unpack per segment; between two segments one
+    # boundary: segment k's fetch and unpack, then segment k+1's launch
+    assert len(by["runner.boundary"]) == len(schedule) - 1
+    for name in ("runner.launch", "runner.fetch", "runner.unpack"):
+        assert len(by[name]) == len(schedule), name
+        assert all(within(x, outer) for x in by[name])
+    for k, b in enumerate(by["runner.boundary"]):
+        assert within(b, outer)
+        assert within(by["runner.fetch"][k], b)
+        assert within(by["runner.unpack"][k], b)
+        assert within(by["runner.launch"][k + 1], b)
+        assert by["runner.fetch"][k][1] <= by["runner.launch"][k + 1][0]
+    assert not within(by["runner.launch"][0], by["runner.boundary"][0])
