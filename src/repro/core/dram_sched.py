@@ -90,20 +90,28 @@ def access(state: DramState, channel, bank, row, app, is_tlb, active,
     rounds in one call, and each round contends only with itself — exactly
     as when the rounds were separate sequential calls. `waves=1` is the
     plain fully-contending batch.
+
+    The per-(channel, bank) and per-(channel, class) tables are read and
+    written by one-hot compare-and-reduce over the lanes, with no gather
+    or scatter: XLA:TPU applies the indices of a gather or scatter one
+    after another, which cost a third of a cycle's device time. `channel`
+    and `bank` must be in range.
     """
     n_channels, n_banks = state.open_row.shape
     cls = classify(state, app, is_tlb, mask_enabled)
 
     N = app.shape[0]
     C = N // waves
-    row_hit = state.open_row[channel, bank] == row
+    cb = channel * n_banks + bank
+    at_cb = cb[:, None] == jnp.arange(n_channels * n_banks)     # (N, C*B)
+    row_hit = (at_cb & (state.open_row.reshape(-1) == row[:, None])).any(1)
     if waves > 1:
         # progressive open rows across waves, per flat position (the same
         # core's earlier sub-access opening the row it re-touches is the
         # dominant sequential row-hit source); cross-position openings and
         # closings between waves are not modeled
         row_w = row.reshape(waves, C)
-        cb_w = (channel * n_banks + bank).reshape(waves, C)
+        cb_w = cb.reshape(waves, C)
         tri_w = jnp.arange(waves)[:, None, None] \
             < jnp.arange(waves)[None, :, None]
         opened = ((row_w[:, None, :] == row_w[None, :, :])
@@ -115,7 +123,7 @@ def access(state: DramState, channel, bank, row, app, is_tlb, active,
 
     # rank = priority ahead of me on my (channel, bank) within my wave —
     # banks service in parallel. (waves, C, C) blocks instead of (N, N).
-    cb = (channel * n_banks + bank).reshape(waves, C)
+    cb = cb.reshape(waves, C)
     key = cls * 2 + (~row_hit) if fr_fcfs else cls * 2
     key = key.reshape(waves, C)
     tri = jnp.arange(C)[None, :] < jnp.arange(C)[:, None]   # j before i
@@ -126,41 +134,38 @@ def access(state: DramState, channel, bank, row, app, is_tlb, active,
     n_ahead = ahead.sum(axis=2).reshape(N)
 
     # standing backlog + EWMA decay toward observed per-class pressure.
-    # With waves > 1 the EWMA chains once per wave (exactly the update the
-    # sequential per-round calls applied 8x per cycle — a single update
-    # with the summed counts would settle ~3x too high) and each wave
-    # reads the backlog its round would have seen.
+    # The EWMA chains once per wave (exactly the update the sequential
+    # per-round calls applied 8x per cycle — a single update with the
+    # summed counts would settle ~3x too high) and each wave reads the
+    # backlog its round would have seen.
     quota = silver_quota(state, thres_max)
     n_apps = state.conc_walks.shape[0]
-    if waves == 1:
-        backlog = state.queue_len[channel, cls]
-        counts = jnp.zeros((n_channels, 3), jnp.int32).at[channel, cls].add(
-            active.astype(jnp.int32))
-        queue_len = (state.queue_len * 3 + counts) // 4
-        served_w = (active & (cls == 1)).sum(dtype=jnp.int32)[None]
-    else:
-        wave_ix = jnp.repeat(jnp.arange(waves, dtype=jnp.int32), C)
-        counts = jnp.zeros((waves, n_channels, 3), jnp.int32).at[
-            wave_ix, channel, cls].add(active.astype(jnp.int32))
-        qs = []
-        queue_len = state.queue_len
-        for k in range(waves):
-            qs.append(queue_len)
-            queue_len = (queue_len * 3 + counts[k]) // 4
-        backlog = jnp.stack(qs)[wave_ix, channel, cls]
-        served_w = (active & (cls == 1)).reshape(waves, C) \
-            .sum(1, dtype=jnp.int32)
+    cq = ((channel * 3 + cls)[:, None] == jnp.arange(n_channels * 3)) \
+        .reshape(waves, C, n_channels * 3)
+    counts = (cq & active.reshape(waves, C)[:, :, None]).sum(
+        1, dtype=jnp.int32).reshape(waves, n_channels, 3)
+    qs = []
+    queue_len = state.queue_len
+    for k in range(waves):
+        qs.append(queue_len)
+        queue_len = (queue_len * 3 + counts[k]) // 4
+    backlog = jnp.where(cq, jnp.stack(qs).reshape(waves, 1, -1), 0) \
+        .sum(2).reshape(N)
+    served_w = (active & (cls == 1)).reshape(waves, C) \
+        .sum(1, dtype=jnp.int32)
 
     latency = service + (n_ahead + backlog) * T_QUEUE_UNIT
     latency = jnp.where(active, latency, 0)
 
     # ---- state updates ----
-    # open rows: last active request per (channel, bank) wins; inactive
-    # lanes are routed out of bounds and dropped — a masked write-back of
-    # the gathered value would let a trailing inactive lane clobber an
-    # earlier active lane's update with the stale cycle-start row
-    new_open = state.open_row.at[
-        jnp.where(active, channel, n_channels), bank].set(row, mode="drop")
+    # open rows: the last active lane per (channel, bank) wins, by a dense
+    # max over lanes; a (channel, bank) no active lane touched keeps its
+    # row, so a trailing inactive lane changes nothing
+    lane = jnp.arange(N, dtype=jnp.int32)
+    last = jnp.where(active[:, None] & at_cb, lane[:, None], -1).max(0)
+    won_row = jnp.where(lane[:, None] == last, row[:, None], 0).sum(0)
+    new_open = jnp.where(last >= 0, won_row, state.open_row.reshape(-1)) \
+        .reshape(n_channels, n_banks)
 
     # silver rotation: consume quota per wave (at most one rotation per
     # wave, like the sequential per-round calls); classification keeps the
