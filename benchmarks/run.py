@@ -45,15 +45,13 @@ def run_kernels():
     """Micro-bench the Pallas kernels. Each row says whether the kernel
     ran compiled or in interpret mode: interpret-mode times show
     correctness and relative shape scaling, never device speed.
-    flash/paged attention compile on a TPU; fused_tlb and ssd_scan do not
-    lower for TPU yet (tests/test_chip_compile.py), so they always run
-    interpreted."""
+    flash/paged attention compile on a TPU; ssd_scan does not lower for
+    TPU yet (tests/test_chip_compile.py), so it always runs interpreted."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.kernels.flash_attention.ops import flash_attention
-    from repro.kernels.fused_tlb.ops import fused_tlb_access
     from repro.kernels.paged_attention.ops import paged_attention
     from repro.kernels.ssd_scan.ops import ssd_scan
 
@@ -87,22 +85,6 @@ def run_kernels():
         g().block_until_ready()
     print(f"paged_attention_b4,{mode},{(time.time()-t0)/3*1e6:.0f},"
           f"{4*4*H*128*dh:.3g}")
-
-    sets, ways, lanes = 64, 16, 48
-    tags = jnp.asarray(rng.choice(1 << 12, (sets, ways)), jnp.int32)
-    asids = jnp.asarray(rng.choice(4, (sets, ways)), jnp.int32)
-    lru = jnp.asarray(rng.choice(1000, (sets, ways)), jnp.int32)
-    vpn = jnp.asarray(rng.choice(1 << 12, lanes), jnp.int32)
-    asid = jnp.asarray(rng.choice(4, lanes), jnp.int32)
-    on = jnp.ones(lanes, jnp.int32)
-    tl = lambda: fused_tlb_access(tags, asids, lru, vpn, asid, on, on,  # noqa
-                                  1001, n_waves=6, interpret=True)[3]
-    tl().block_until_ready()
-    t0 = time.time()
-    for _ in range(3):
-        tl().block_until_ready()
-    print(f"fused_tlb_{lanes}lane,interpret,"
-          f"{(time.time()-t0)/3*1e6:.0f},n/a")
 
     x = jnp.asarray(rng.randn(1, 256, 8, 32) * .3, jnp.float32)
     dt = jnp.asarray(np.abs(rng.randn(1, 256, 8)) * .1 + .02, jnp.float32)

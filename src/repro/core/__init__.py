@@ -8,10 +8,8 @@
   dram_sched  — golden/silver/normal scheduler with Eq. (1) quotas (§5.4)
   design      — composable design points: per-layer policy specs +
                 registry (register_design / get_design / list_designs)
-  mask        — legacy MaskConfig/DesignPoint + design(name) compat shims
 """
-from repro.core.design import (BypassSpec, Design, DramSpec,  # noqa: F401
-                               PartitionSpec, TokenSpec, TranslationSpec,
-                               get_design, list_designs, register_design)
-from repro.core.mask import (ALL_DESIGNS, DesignPoint,  # noqa: F401
-                             MaskConfig, design)
+from repro.core.design import (PAPER_DESIGNS, BypassSpec,  # noqa: F401
+                               Design, DramSpec, PartitionSpec, TokenSpec,
+                               TranslationSpec, get_design, list_designs,
+                               register_design)

@@ -27,8 +27,7 @@ Specs are plain frozen dataclasses: hashable (so a `SimConfig` carrying a
 `Design` keys jit/compile caches correctly) and static under jit (stage
 dispatch in `repro.sim.memsys` branches on them at trace time).
 
-`repro.core.mask` keeps the legacy `DesignPoint`/`MaskConfig` dataclasses
-and the `design(name)` / `ALL_DESIGNS` shims on top of this registry.
+`PAPER_DESIGNS` names the paper's eight built-in design points.
 """
 from __future__ import annotations
 
@@ -81,6 +80,22 @@ class PartitionSpec:
         if self.kind not in PARTITION_KINDS:
             raise ValueError(f"partition kind {self.kind!r} not in "
                              f"{PARTITION_KINDS}")
+
+
+def static_partition_index(index, n_resources: int, n_apps: int, app):
+    """Static resource partitioning (the `Static` design, §6): app `a` owns
+    a contiguous ~1/n_apps slice of an index space (L2 sets, DRAM channels).
+    Slice bounds are proportional ((a*n)//n_apps .. ((a+1)*n)//n_apps) so no
+    trailing resources are stranded when n_apps does not divide n_resources;
+    if there are fewer resources than apps the slice floor is one unit and
+    the result clips into range.
+
+    index/app may be traced arrays; n_resources/n_apps are static ints.
+    """
+    na = max(n_apps, 1)
+    start = (app * n_resources) // na
+    span = jnp.maximum((app + 1) * n_resources // na - start, 1)
+    return jnp.minimum(start + index % span, n_resources - 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,46 +178,6 @@ class Design:
         return dataclasses.replace(self, **updates)
 
     replace = with_
-
-    # ------------------------------------------------- legacy flag views
-    # Read-only views matching the pre-registry `DesignPoint` flag bag, so
-    # code written against `design(name).mask.epoch_cycles` etc. keeps
-    # working unchanged.
-
-    @property
-    def ideal_tlb(self) -> bool:
-        return self.translation.kind == "ideal"
-
-    @property
-    def use_pwc(self) -> bool:
-        return self.translation.kind == "pwc"
-
-    @property
-    def use_l2_tlb(self) -> bool:
-        return self.translation.kind in ("shared_l2_tlb", "ideal")
-
-    @property
-    def static_partition(self) -> bool:
-        return self.partition.kind == "static"
-
-    @property
-    def mask(self):
-        from repro.core.mask import MaskConfig
-        return MaskConfig(
-            tlb_tokens=self.tokens.enabled,
-            l2_bypass=self.bypass.enabled,
-            dram_sched=self.dram.enabled,
-            l1_tlb_entries=self.translation.l1_entries,
-            l2_tlb_entries=self.translation.l2_entries,
-            l2_tlb_ways=self.translation.l2_ways,
-            bypass_cache_entries=self.tokens.bypass_cache_entries,
-            epoch_cycles=self.epoch_cycles,
-            initial_token_frac=self.tokens.initial_frac,
-            token_step_frac=self.tokens.step_frac,
-            thres_max=self.dram.thres_max,
-            walk_levels=self.translation.walk_levels,
-            max_concurrent_walks=self.translation.max_concurrent_walks,
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -321,56 +296,13 @@ def design_params(d) -> DesignParams:
     return jax.tree_util.tree_map(jnp.asarray, host_design_params(d))
 
 
-def from_legacy(dp) -> Design:
-    """Convert a legacy `repro.core.mask.DesignPoint` to a `Design`."""
-    if isinstance(dp, Design):
-        return dp
-    m = dp.mask
-    if dp.ideal_tlb:
-        kind = "ideal"
-    elif dp.use_pwc:
-        if dp.use_l2_tlb:
-            # the old pipeline would run BOTH the shared L2 TLB and the
-            # PWC for this flag combo; no TranslationSpec kind expresses
-            # that, so refuse rather than silently drop one of them
-            raise ValueError(
-                f"legacy DesignPoint {dp.name!r} sets both use_l2_tlb and "
-                "use_pwc; that combination has no Design equivalent — "
-                "pick one translation organization")
-        kind = "pwc"
-    elif dp.use_l2_tlb:
-        kind = "shared_l2_tlb"
-    else:
-        kind = "walk_only"
-    return Design(
-        name=dp.name,
-        translation=TranslationSpec(
-            kind=kind, l1_entries=m.l1_tlb_entries,
-            l2_entries=m.l2_tlb_entries, l2_ways=m.l2_tlb_ways,
-            walk_levels=m.walk_levels,
-            max_concurrent_walks=m.max_concurrent_walks),
-        partition=PartitionSpec(
-            "static" if dp.static_partition else "shared"),
-        tokens=TokenSpec(enabled=m.tlb_tokens,
-                         initial_frac=m.initial_token_frac,
-                         step_frac=m.token_step_frac,
-                         bypass_cache_entries=m.bypass_cache_entries),
-        bypass=BypassSpec(enabled=m.l2_bypass),
-        dram=DramSpec("mask" if m.dram_sched else "fr_fcfs",
-                      thres_max=m.thres_max),
-        epoch_cycles=m.epoch_cycles,
-    )
-
-
 def as_design(d) -> Design:
-    """Normalize str | Design | legacy DesignPoint to a Design."""
+    """Normalize a registered name or a Design to a Design."""
     if isinstance(d, Design):
         return d
     if isinstance(d, str):
         return get_design(d)
-    if hasattr(d, "mask") and hasattr(d, "name"):  # legacy DesignPoint
-        return from_legacy(d)
-    raise TypeError(f"not a design name/Design/DesignPoint: {d!r}")
+    raise TypeError(f"not a design name or Design: {d!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -386,8 +318,6 @@ def register_design(d: Design, *, overwrite: bool = False) -> Design:
     Refuses to silently shadow an existing *different* design (re-registering
     an identical one is a no-op) unless `overwrite=True`.
     """
-    if not isinstance(d, Design):
-        d = as_design(d)
     prev = _REGISTRY.get(d.name)
     if prev is not None and prev != d and not overwrite:
         raise ValueError(
@@ -431,3 +361,6 @@ BUILTIN_DESIGNS: Tuple[Design, ...] = (
 
 for _d in BUILTIN_DESIGNS:
     register_design(_d)
+
+# the paper's named designs (the registry may hold user designs beyond these)
+PAPER_DESIGNS: Tuple[str, ...] = tuple(d.name for d in BUILTIN_DESIGNS)

@@ -139,18 +139,16 @@ def fill_bank(state: TLBState, vpn, asid, do_fill, time) -> TLBState:
     return state._replace(tags=tags, asids=asids, lru=lru)
 
 
-# names the round in the HLO metadata (both backends), nested under the
-# calling memsys stage's scope; see `sim.memsys.step`
+# names the round in the HLO metadata, nested under the calling memsys
+# stage's scope; see `sim.memsys.step`
 @jax.named_scope("mem.fused_tlb")
-def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
-                 n_waves: int = 1, track_asids: bool = True,
-                 backend: str = "xla",
-                 ) -> Tuple[TLBState, jax.Array, jax.Array]:
+def access_fused(state: TLBState, line, active, may_fill, time,
+                 n_waves: int = 1) -> Tuple[TLBState, jax.Array, jax.Array]:
     """One-call probe+fill for a whole cycle's sub-accesses ("waves").
 
     The simulator's shared L2 data cache used to be accessed by 8 dependent
     probe/fill/DRAM rounds per cycle (4 page-walk levels + 4 divergent data
-    lines). This kernel services all of them in one batch: the lanes are
+    lines). This round services all of them in one batch: the lanes are
     `n_waves` contiguous equal groups ("waves", the old rounds in order),
     and the cross-wave semantics that matter are kept:
 
@@ -183,44 +181,19 @@ def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
     way). A set also accepts at most n_ways fills per cycle (relevant
     only when n_waves > n_ways): overflow winners go to DRAM unfilled.
 
-    vpn/asid/active/may_fill: (N,) with N divisible by n_waves.
-    `track_asids=False` skips the ASID plane entirely (tag-only caches
-    like the line-addressed L2$, whose tags are already unique).
+    The cache is tag-only: both callers (the line-addressed L2$ and the
+    PWC) use tags that are already unique, so `state.asids` passes through
+    untouched. line/active/may_fill: (N,) with N divisible by n_waves.
     Returns (state', hit (N,) bool, filled (N,) bool).
-
-    `backend` selects the implementation of the round itself:
-    "xla" (default) is the inline jnp path below; "pallas" lowers the
-    `kernels/fused_tlb` Pallas kernel (TPU/GPU — raises elsewhere, no
-    silent fallback); "pallas-interpret" runs the same kernel through the
-    Pallas interpreter on any platform. The counter arithmetic is shared,
-    and the kernel mirrors this function op for op, so all backends are
-    bit-for-bit identical — `sim/config.py::SimConfig.tlb_backend`
-    resolves the knob (env `REPRO_TLB_BACKEND`) and threads it here.
     """
-    if backend not in (None, "xla"):
-        # lazy import: the Pallas machinery stays off the default path
-        from repro.kernels.fused_tlb.ops import fused_tlb_access
-        tags, asids, lru, hit_i, filled_i = fused_tlb_access(
-            state.tags, state.asids, state.lru, vpn,
-            jnp.asarray(asid, jnp.int32), active, may_fill, time,
-            n_waves=n_waves, track_asids=track_asids,
-            interpret=True if backend == "pallas-interpret" else None)
-        hit = hit_i != 0
-        filled = filled_i != 0
-        hits = state.hits + hit.sum(dtype=jnp.int32)
-        misses = state.misses + (active & ~hit).sum(dtype=jnp.int32)
-        return (state._replace(tags=tags, asids=asids, lru=lru,
-                               hits=hits, misses=misses), hit, filled)
     n_sets, n_ways = state.tags.shape
-    N = vpn.shape[0]
+    N = line.shape[0]
     W = n_waves
     C = N // W
-    set_ix = (vpn % n_sets if n_sets > 1
-              else jnp.zeros_like(vpn)).astype(jnp.int32)
+    set_ix = (line % n_sets if n_sets > 1
+              else jnp.zeros_like(line)).astype(jnp.int32)
     rows_t = state.tags[set_ix]                  # (N, ways)
-    match = rows_t == vpn[:, None]
-    if track_asids:
-        match = match & (state.asids[set_ix] == asid[:, None])
+    match = rows_t == line[:, None]
     pre_hit = match.any(axis=1) & active
     way = jnp.argmax(match, axis=1)
 
@@ -229,7 +202,7 @@ def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
     if W > 1:
         # duplicate suppression per flat position (core): an earlier-wave
         # candidate with the same line makes later waves forward, not fill
-        lines_wc = vpn.reshape(W, C)
+        lines_wc = line.reshape(W, C)
         cand_wc = cand.reshape(W, C)
         tri_w = jnp.arange(W)[:, None, None] < jnp.arange(W)[None, :, None]
         dup = ((lines_wc[:, None, :] == lines_wc[None, :, :])
@@ -268,30 +241,24 @@ def access_fused(state: TLBState, vpn, asid, active, may_fill, time,
 
     # ---- one merged update pass per plane ---------------------------------
     # pre-hit lanes touch their way, winners fill their victim — both
-    # write tag=vpn (a pre-hit lane's matched tag IS its vpn) and
+    # write tag=line (a pre-hit lane's matched tag IS its line) and
     # lru=time, so each plane is ONE flat scatter; other lanes are routed
     # out of bounds and dropped
     flat = jnp.where(pre_hit, set_ix * n_ways + way,
                      jnp.where(winner, set_ix * n_ways + victim,
                                n_sets * n_ways))
     shape = state.tags.shape
-    tags = state.tags.reshape(-1).at[flat].set(vpn, mode="drop").reshape(shape)
+    tags = state.tags.reshape(-1).at[flat].set(line, mode="drop").reshape(
+        shape)
     lru = state.lru.reshape(-1).at[flat].set(time, mode="drop").reshape(shape)
-    if track_asids:
-        asids = state.asids.reshape(-1).at[flat].set(
-            asid, mode="drop").reshape(shape)
-    else:
-        asids = state.asids
 
     # ---- final hit resolution (forwarding falls out of the fills) ---------
-    post = tags[set_ix] == vpn[:, None]
-    if track_asids:
-        post = post & (asids[set_ix] == asid[:, None])
+    post = tags[set_ix] == line[:, None]
     hit = pre_hit | (active & ~winner & post.any(axis=1))
     hits = state.hits + hit.sum(dtype=jnp.int32)
     misses = state.misses + (active & ~hit).sum(dtype=jnp.int32)
-    return (state._replace(tags=tags, asids=asids, lru=lru,
-                           hits=hits, misses=misses), hit, winner)
+    return (state._replace(tags=tags, lru=lru, hits=hits, misses=misses),
+            hit, winner)
 
 
 def flush_asid(state: TLBState, asid: int) -> TLBState:

@@ -2,12 +2,11 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 #
-# fused_tlb/ is the simulator's hot spot: the fused cross-wave shared
-# L2$/PWC round (core/tlb.py::access_fused) as a Pallas kernel, selected
-# via SimConfig.tlb_backend / REPRO_TLB_BACKEND (xla | pallas |
-# pallas-interpret) and parity-pinned bit-for-bit against the XLA path.
-# It replaces the retired seed tlb_probe/ kernel, whose single-round
-# probe+fill contract predated the fused semantics.
+# The simulator's hot spot, the fused cross-wave shared L2$/PWC round,
+# has no kernel here: it runs as XLA (core/tlb.py::access_fused). A
+# Pallas round belongs here only once it replaces that path after winning
+# on the chip, never as a second path behind a switch. The kernels here
+# serve the model stack (attention, SSD scan).
 #
 # Every ops.py wrapper takes `interpret`: None lowers for real on a
 # platform that has the lowering and raises anywhere else; interpret mode
@@ -16,16 +15,15 @@
 import jax
 
 
-def resolve_interpret(interpret, kernel: str, platforms=("tpu",),
-                      hint: str = "") -> bool:
+def resolve_interpret(interpret, kernel: str) -> bool:
     """`interpret` for a kernel call: True/False pass through; None means
-    "lower for real", legal only when the default backend is one of
-    `platforms` — elsewhere it raises rather than silently interpreting."""
+    "lower for real", legal only when the default backend is the TPU —
+    elsewhere it raises rather than silently interpreting."""
     if interpret is not None:
         return bool(interpret)
     backend = jax.default_backend()
-    if backend not in platforms:
+    if backend != "tpu":
         raise RuntimeError(
             f"{kernel}: no Pallas lowering for platform {backend!r}; pass "
-            f"interpret=True{hint} to run the interpreter explicitly")
+            "interpret=True to run the interpreter explicitly")
     return False

@@ -8,49 +8,12 @@ truth for the scheduler, token distribution, and stats attribution.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import TYPE_CHECKING, Optional, Tuple
 
 from repro.core.design import Design, as_design, get_design
 
 if TYPE_CHECKING:   # sim.faults imports this module; annotation only
     from repro.sim.faults import FaultPlan
-
-TLB_BACKENDS = ("xla", "pallas", "pallas-interpret")
-
-
-def resolve_tlb_backend(value: Optional[str] = None) -> str:
-    """Resolve the fused-round backend knob to a concrete value.
-
-    None defers to env `REPRO_TLB_BACKEND` (default "xla"). "pallas"
-    demands a real lowering: on platforms without one (CPU) it raises
-    rather than silently interpreting, unless `REPRO_TLB_INTERPRET=1`
-    explicitly opts into the interpreter (then it resolves to
-    "pallas-interpret"). The resolved string is stored on SimConfig, so
-    it participates in the frozen-dataclass hash and keys the runner's
-    compile caches correctly.
-    """
-    v = value if value is not None else os.environ.get(
-        "REPRO_TLB_BACKEND", "xla")
-    v = v.strip().lower().replace("_", "-")
-    if v not in TLB_BACKENDS:
-        raise ValueError(
-            f"tlb_backend must be one of {TLB_BACKENDS}, got {v!r}")
-    if v == "pallas":
-        import jax
-        platform = jax.default_backend()
-        if platform not in ("tpu", "gpu"):
-            if os.environ.get("REPRO_TLB_INTERPRET", "") in ("1", "true",
-                                                             "yes"):
-                v = "pallas-interpret"
-            else:
-                raise RuntimeError(
-                    f"tlb_backend='pallas' requested but platform "
-                    f"{platform!r} has no Pallas lowering; set "
-                    "tlb_backend='pallas-interpret' (or "
-                    "REPRO_TLB_INTERPRET=1) to run the interpreter "
-                    "explicitly, or use the 'xla' backend")
-    return v
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,12 +36,9 @@ class SimConfig:
     lat_l2_cache: int = 10
     lat_l1_data: int = 1
     sim_cycles: int = 60_000
-    # a repro.core.design.Design; a name or legacy DesignPoint is coerced
+    # a repro.core.design.Design; a registered name is coerced
     design: Design = dataclasses.field(
         default_factory=lambda: get_design("gpu-mmu"))
-    # fused shared-round backend: "xla" | "pallas" | "pallas-interpret";
-    # None resolves from env REPRO_TLB_BACKEND (see resolve_tlb_backend)
-    tlb_backend: Optional[str] = None
     # deterministic chaos schedule for `runner.run_trace` (sim.faults).
     # Hashable and part of the config identity, but stripped by the
     # runner's compile-cache canonicalization: fault operands are data,
@@ -92,8 +52,6 @@ class SimConfig:
                 f"got {self.n_apps}")
         if not isinstance(self.design, Design):
             object.__setattr__(self, "design", as_design(self.design))
-        object.__setattr__(self, "tlb_backend",
-                           resolve_tlb_backend(self.tlb_backend))
 
     @property
     def total_warps(self) -> int:

@@ -62,8 +62,7 @@ from repro.core import dram_sched
 from repro.core import page_table as pt_mod
 from repro.core import tlb as tlb_mod
 from repro.core import tokens as tok_mod
-from repro.core.design import DesignParams
-from repro.core.mask import static_partition_index
+from repro.core.design import DesignParams, static_partition_index
 from repro.core.page_table import _mix
 from repro.sim.config import SimConfig
 from repro.sim.workloads import FIELD, gen_vpn
@@ -409,9 +408,8 @@ def translation_probe(cfg: SimConfig, dp: DesignParams, trans: TransState,
     pwc, pwc_hit = jax.lax.cond(
         dp.use_pwc,
         lambda st: tlb_mod.access_fused(
-            st, walk_lines, jnp.zeros_like(walk_lines), walk_active,
-            jnp.ones((L * C,), bool), t, n_waves=L, track_asids=False,
-            backend=cfg.tlb_backend)[:2],
+            st, walk_lines, walk_active, jnp.ones((L * C,), bool), t,
+            n_waves=L)[:2],
         lambda st: (st, jnp.zeros((L * C,), bool)),
         trans.pwc)
     walk_go = walk_active & ~pwc_hit
@@ -518,11 +516,10 @@ def shared_memory_access(cfg: SimConfig, dp: DesignParams, data: DataState,
         lines % cfg.n_channels).astype(jnp.int32)
 
     # reuse TLB machinery: tag = full line id (unique, so the line cache
-    # is tag-only and the ASID plane is skipped entirely)
+    # is tag-only)
     l2c, hit, _ = tlb_mod.access_fused(
-        l2c, lines * cfg.l2_sets + key, jnp.zeros_like(lines), go,
-        may_fill, t, n_waves=max(L + K, 1), track_asids=False,
-        backend=cfg.tlb_backend)
+        l2c, lines * cfg.l2_sets + key, go, may_fill, t,
+        n_waves=max(L + K, 1))
     lat = jnp.where(hit, cfg.lat_l2_cache, 0)
     miss = go & ~hit
 

@@ -6,9 +6,9 @@ Two API levels share one compiled core:
   entries are idle partners) and returns a per-app stats dict.
   `run_pair` / `run_solo` are thin 2-app wrappers kept for the paper's
   pair-based experiments; `run_batch` vmaps many same-size mixes through
-  one compile. `design` is a registered name, a `repro.core.design.Design`
-  (including user-registered or ad-hoc compositions), or a legacy
-  `DesignPoint`.
+  one compile. `design` is a registered name or a
+  `repro.core.design.Design` (including user-registered or ad-hoc
+  compositions).
 
 * Typed: `Experiment(design, mixes, cycles).run()` returns an
   `ExperimentResult` of `MixResult`/`AppStats` objects with the derived
@@ -73,7 +73,7 @@ jax.config.update("jax_enable_x64", False)
 
 _span = jax.profiler.TraceAnnotation
 
-DesignLike = Union[str, Design]  # legacy DesignPoint also accepted
+DesignLike = Union[str, Design]
 
 # incremented every time a simulator program is traced for compilation
 # (once per jit/vmap wrapper; re-executions hit the cache and do not
@@ -952,11 +952,11 @@ def _assemble_result(design: Design, cycles: int, n_results: int,
 class Experiment:
     """Typed façade over `run_batch`: a design × a list of mixes.
 
-    `design` may be a registered name, a `Design`, or a legacy
-    `DesignPoint`; `mixes` entries are bench tuples (a bare bench name
-    means a 1-app run; None entries are idle partners). Mixes of
-    different sizes are allowed — each (design, n_apps) group is one
-    vmapped compile, with the solo baselines batched into the same call.
+    `design` may be a registered name or a `Design`; `mixes` entries are
+    bench tuples (a bare bench name means a 1-app run; None entries are
+    idle partners). Mixes of different sizes are allowed — each
+    (design, n_apps) group is one vmapped compile, with the solo
+    baselines batched into the same call.
 
         exp = Experiment("mask", [("3DS", "BLK"), ("MUM", "RED")])
         res = exp.run()

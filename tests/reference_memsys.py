@@ -23,7 +23,7 @@ from repro.core import dram_sched
 from repro.core import page_table as pt_mod
 from repro.core import tlb as tlb_mod
 from repro.core import tokens as tok_mod
-from repro.core.mask import static_partition_index
+from repro.core.design import static_partition_index
 from repro.core.page_table import _mix
 from repro.sim.config import SimConfig
 from repro.sim.workloads import FIELD, gen_vpn

@@ -133,22 +133,6 @@ def test_paged_attention_qwen3_4b(one_chip):
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError, reason=(
-    "Mosaic refuses the dynamic row gather `rows_t = tags[set_ix]` at "
-    "kernels/fused_tlb/kernel.py:64: ValueError: Shape mismatch in input, "
-    "indices and output"))
-def test_fused_tlb_l2_cache_size(one_chip):
-    """`tlb_backend="pallas"` at the shared L2$ size: 1024 sets x 16 ways,
-    240 lanes in 8 waves."""
-    from repro.kernels.fused_tlb.ops import fused_tlb_access
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
-                                          sharding=one_chip)
-    fused_tlb_access.lower(
-        i32(1024, 16), i32(1024, 16), i32(1024, 16), i32(240), i32(240),
-        i32(240), i32(240), i32(), n_waves=8, track_asids=False,
-        interpret=False).compile()
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
     "the (1, 1, chunk, head_tile) block at kernels/ssd_scan/kernel.py:86 "
     "breaks the Pallas TPU rule that a block's last two dims divide by "
     "(8, 128) or equal the array's"))

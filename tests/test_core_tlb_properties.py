@@ -11,6 +11,7 @@ hypothesis = pytest.importorskip("hypothesis")
 import hypothesis.strategies as st  # noqa: E402
 from hypothesis import given, settings  # noqa: E402
 
+from perfbench import reference  # noqa: E402
 from repro.core import page_table as pt  # noqa: E402
 from repro.core import tlb as tlb_mod  # noqa: E402
 
@@ -34,32 +35,29 @@ def test_tlb_property_fill_probe(vpns, asid):
 
 
 # ------------------------------------------------------- access_fused
-# The fused-round contract, checked identically against both backends
-# (the inline XLA path and the Pallas kernel in interpret mode) from an
-# empty cache (tags -1, random LRU): every tag change is then a fill,
-# which makes the port/victim/forwarding properties directly observable.
+# The fused-round contract, checked from an empty cache (tags -1, random
+# LRU): every tag change is then a fill, which makes the
+# port/victim/forwarding properties directly observable.
 
 _SETS, _WAYS = 4, 2
 
 
-def _fused_round(backend, lru0, vpn, act, mf, n_waves):
+def _fused_round(lru0, vpn, act, mf, n_waves):
     tags = jnp.full((_SETS, _WAYS), -1, jnp.int32)
     state = tlb_mod.TLBState(
         tags=tags, asids=jnp.full((_SETS, _WAYS), -1, jnp.int32),
         lru=jnp.asarray(lru0, jnp.int32).reshape(_SETS, _WAYS),
         hits=jnp.zeros((), jnp.int32), misses=jnp.zeros((), jnp.int32))
     state, hit, filled = tlb_mod.access_fused(
-        state, jnp.asarray(vpn, jnp.int32), jnp.zeros(len(vpn), jnp.int32),
-        jnp.asarray(act), jnp.asarray(mf), 7,
-        n_waves=n_waves, track_asids=False, backend=backend)
+        state, jnp.asarray(vpn, jnp.int32), jnp.asarray(act),
+        jnp.asarray(mf), 7, n_waves=n_waves)
     return (np.asarray(state.tags), np.asarray(state.lru),
             np.asarray(hit), np.asarray(filled))
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
 @settings(max_examples=25, deadline=None)
 @given(st.data())
-def test_access_fused_contract_properties(backend, data):
+def test_access_fused_contract_properties(data):
     W = data.draw(st.sampled_from([1, 2, 3]), label="n_waves")
     C = data.draw(st.sampled_from([1, 2, 4]), label="lanes_per_wave")
     N = W * C
@@ -72,7 +70,7 @@ def test_access_fused_contract_properties(backend, data):
     lru0 = np.asarray(data.draw(st.lists(
         st.integers(0, 50), min_size=_SETS * _WAYS,
         max_size=_SETS * _WAYS))).reshape(_SETS, _WAYS)
-    tags1, lru1, hit, filled = _fused_round(backend, lru0, vpn, act, mf, W)
+    tags1, lru1, hit, filled = _fused_round(lru0, vpn, act, mf, W)
 
     set_ix = vpn % _SETS
     wave = np.arange(N) // C
@@ -99,7 +97,9 @@ def test_access_fused_contract_properties(backend, data):
 
 @settings(max_examples=25, deadline=None)
 @given(st.data())
-def test_access_fused_backends_bitwise_equal(data):
+def test_access_fused_equals_reference_bitwise(data):
+    """`access_fused` == the plain reference's `line_round`: tags, LRU
+    and hits, bit for bit."""
     W = data.draw(st.sampled_from([1, 2, 3]))
     C = data.draw(st.sampled_from([1, 2, 4]))
     N = W * C
@@ -109,10 +109,14 @@ def test_access_fused_backends_bitwise_equal(data):
     lru0 = np.asarray(data.draw(st.lists(
         st.integers(0, 50), min_size=_SETS * _WAYS,
         max_size=_SETS * _WAYS))).reshape(_SETS, _WAYS)
-    a = _fused_round("xla", lru0, vpn, act, mf, W)
-    b = _fused_round("pallas-interpret", lru0, vpn, act, mf, W)
-    for xa, xb, name in zip(a, b, ("tags", "lru", "hit", "filled")):
-        np.testing.assert_array_equal(xa, xb, err_msg=name)
+    tags, lru, hit, _ = _fused_round(lru0, vpn, act, mf, W)
+    want, want_hit = reference.line_round(
+        dict(tag=jnp.full((_SETS, _WAYS), -1, jnp.int32),
+             lru=jnp.asarray(lru0, jnp.int32)),
+        jnp.asarray(vpn, jnp.int32), jnp.asarray(act), jnp.asarray(mf), 7, W)
+    for got, exp, name in ((tags, want["tag"], "tags"),
+                           (lru, want["lru"], "lru"), (hit, want_hit, "hit")):
+        np.testing.assert_array_equal(got, np.asarray(exp), err_msg=name)
 
 
 @settings(max_examples=30, deadline=None)

@@ -17,9 +17,8 @@ Three layers:
 import numpy as np
 import pytest
 
-from repro.core.design import (DesignParams, canonical_design, design_params,
-                               get_design, static_signature)
-from repro.core.mask import ALL_DESIGNS
+from repro.core.design import (PAPER_DESIGNS, DesignParams, canonical_design,
+                               design_params, get_design, static_signature)
 from repro.sim import runner
 from repro.sim.runner import Experiment, run_grid, run_mix, sweep
 
@@ -29,14 +28,14 @@ CYCLES = 1_200       # matches the float-hex goldens' executable
 # ------------------------------------------------------ signature contracts
 
 def test_builtin_designs_group_into_two_signatures():
-    sigs = {name: static_signature(get_design(name)) for name in ALL_DESIGNS}
+    sigs = {name: static_signature(get_design(name)) for name in PAPER_DESIGNS}
     groups = {}
     for name, sig in sigs.items():
         groups.setdefault(sig, []).append(name)
     assert len(groups) == 2
     assert groups[sigs["ideal"]] == ["ideal"]
     assert sorted(groups[sigs["mask"]]) == sorted(
-        n for n in ALL_DESIGNS if n != "ideal")
+        n for n in PAPER_DESIGNS if n != "ideal")
 
 
 def test_signature_stable_under_dynamic_knobs():
@@ -106,8 +105,8 @@ def _hexed(s):
 def test_grid_matches_loop_bitforbit(n_apps, mix):
     """run_grid over all 8 designs == per-design run_mix, float-hex exact
     (so the grid path inherits the GOLDEN pins of test_memsys_stages)."""
-    grid = run_grid(list(ALL_DESIGNS), [mix], cycles=CYCLES)
-    for i, name in enumerate(ALL_DESIGNS):
+    grid = run_grid(list(PAPER_DESIGNS), [mix], cycles=CYCLES)
+    for i, name in enumerate(PAPER_DESIGNS):
         loop = _hexed(run_mix(name, list(mix), cycles=CYCLES))
         got = _hexed(grid[i][0])
         assert got == loop, f"{name} n_apps={n_apps} drifted from loop"
@@ -141,12 +140,12 @@ def test_full_sweep_traces_one_program_per_signature_group():
     mixes = [("3DS", "BLK"), ("MUM", "RED")]
     cycles = 977          # unique -> cannot reuse another test's programs
     before = runner.TRACE_COUNT
-    res = sweep(list(ALL_DESIGNS), mixes, cycles=cycles)
+    res = sweep(list(PAPER_DESIGNS), mixes, cycles=cycles)
     assert runner.TRACE_COUNT - before == 2, \
         "expected ONE traced program per signature group"
-    assert set(res) == set(ALL_DESIGNS)
-    again = sweep(list(ALL_DESIGNS), mixes, cycles=cycles)
+    assert set(res) == set(PAPER_DESIGNS)
+    again = sweep(list(PAPER_DESIGNS), mixes, cycles=cycles)
     assert runner.TRACE_COUNT - before == 2, "re-sweep must not retrace"
-    for name in ALL_DESIGNS:
+    for name in PAPER_DESIGNS:
         for a, b in zip(res[name], again[name]):
             assert _hexed(a.raw) == _hexed(b.raw)

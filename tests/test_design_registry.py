@@ -11,9 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.design import (Design, TokenSpec, as_design, from_legacy,
+from repro.core.design import (PAPER_DESIGNS, Design, TokenSpec, as_design,
                                get_design, list_designs, register_design)
-from repro.core.mask import ALL_DESIGNS, DesignPoint, MaskConfig
 from repro.sim import runner
 from repro.sim.config import SimConfig
 from repro.sim.runner import Experiment
@@ -33,7 +32,7 @@ def _small_run(design: Design, n_apps: int):
 
 def test_builtins_registered():
     names = list_designs()
-    for n in ALL_DESIGNS:
+    for n in PAPER_DESIGNS:
         assert n in names
         assert get_design(n).name == n
     with pytest.raises(KeyError):
@@ -41,7 +40,7 @@ def test_builtins_registered():
 
 
 def test_designs_hashable_frozen_distinct():
-    ds = [get_design(n) for n in ALL_DESIGNS]
+    ds = [get_design(n) for n in PAPER_DESIGNS]
     assert len({hash(d) for d in ds}) >= 2     # hashable at all
     assert len(set(ds)) == len(ds)             # all distinct by value
     with pytest.raises(dataclasses.FrozenInstanceError):
@@ -76,31 +75,19 @@ def test_register_collision_semantics():
     assert get_design("t-collide") == d2
 
 
-def test_as_design_legacy_roundtrip():
-    """A legacy flag-bag DesignPoint converts to the same Design the
-    registry serves (modulo nothing — field for field)."""
-    legacy = DesignPoint("mask", mask=MaskConfig())
-    assert as_design(legacy) == get_design("mask")
-    assert from_legacy(legacy) is not legacy
-    base_off = MaskConfig(tlb_tokens=False, l2_bypass=False,
-                          dram_sched=False)
-    assert as_design(DesignPoint("ideal", ideal_tlb=True, mask=base_off)) \
-        == get_design("ideal")
-    assert as_design(DesignPoint("pwc", use_l2_tlb=False, use_pwc=True,
-                                 mask=base_off)) == get_design("pwc")
-    assert as_design("static") == get_design("static")
+def test_as_design_accepts_names_and_designs():
+    """A registered name resolves to the registered design, a Design
+    passes through as it is, and anything else is refused."""
+    for n in PAPER_DESIGNS:
+        assert as_design(n) == get_design(n)
+        assert as_design(get_design(n)) is get_design(n)
     with pytest.raises(TypeError):
         as_design(42)
-    # the old pipeline ran shared L2 TLB + PWC together for this combo;
-    # no spec kind expresses that, so conversion must refuse loudly
-    with pytest.raises(ValueError, match="use_l2_tlb and.*use_pwc"):
-        from_legacy(DesignPoint("bad", use_l2_tlb=True, use_pwc=True,
-                                mask=base_off))
 
 
 # ------------------------------------------------------------- jit grid
 
-@pytest.mark.parametrize("name", ALL_DESIGNS)
+@pytest.mark.parametrize("name", PAPER_DESIGNS)
 def test_every_design_compiles_and_is_finite(name):
     """Each registered design compiles under jit for n_apps in {1, 2, 3}
     and yields finite stats."""

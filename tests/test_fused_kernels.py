@@ -8,7 +8,9 @@ Three layers of evidence that the fusion did not change the model:
     bit-for-bit;
   * contract — `access_fused`'s documented cross-wave semantics
     (per-(set, wave) fill ports, duplicate suppression, forwarding, LRU
-    victim chains) hold on constructed scenarios;
+    victim chains) hold on constructed scenarios, and the round equals
+    the plain reference's `line_round` bit for bit at the L2$ and PWC
+    sizes;
   * statistical — the fused pipeline tracks the frozen sequential
     reference (`tests/reference_memsys.py`, the exact pre-fusion code)
     across ALL registered designs x n_apps in {1, 2, 3} within tight
@@ -20,9 +22,9 @@ import numpy as np
 import pytest
 
 import reference_memsys as ref
+from perfbench import reference
 from repro.core import tlb as tlb_mod
-from repro.core.design import get_design
-from repro.core.mask import ALL_DESIGNS
+from repro.core.design import PAPER_DESIGNS, get_design
 from repro.sim import memsys
 from repro.sim import runner
 from repro.sim.config import SimConfig
@@ -163,9 +165,8 @@ def test_access_fused_forwarding():
     st = _mini_cache()
     # lanes: wave0 = [line 8, line 8], wave1 = [line 8, line 12]
     vpn = jnp.asarray([8, 8, 8, 12], jnp.int32)
-    z = jnp.zeros(4, jnp.int32)
     on = jnp.ones(4, bool)
-    st, hit, filled = tlb_mod.access_fused(st, vpn, z, on, on, 1, n_waves=2)
+    st, hit, filled = tlb_mod.access_fused(st, vpn, on, on, 1, n_waves=2)
     assert hit.tolist() == [False, True, True, False]
     assert filled.tolist() == [True, False, False, True]
 
@@ -176,9 +177,8 @@ def test_access_fused_per_set_per_wave_port():
     st = _mini_cache(sets=4, ways=2)
     # set = vpn % 4: lanes 0,1 both set 1 in wave 0; lane 2 set 1 in wave 1
     vpn = jnp.asarray([5, 9, 13, 2], jnp.int32)
-    z = jnp.zeros(4, jnp.int32)
     on = jnp.ones(4, bool)
-    st, hit, filled = tlb_mod.access_fused(st, vpn, z, on, on, 1, n_waves=2)
+    st, hit, filled = tlb_mod.access_fused(st, vpn, on, on, 1, n_waves=2)
     assert filled.tolist() == [True, False, True, True]
     assert not bool(hit[1])              # port loss -> miss, no forward
     # both same-set winners landed in DISTINCT ways (LRU victim chain)
@@ -192,9 +192,8 @@ def test_access_fused_duplicate_suppression_same_position():
     st = _mini_cache()
     # one core (C=1), 3 waves, same line every wave
     vpn = jnp.asarray([6, 6, 6], jnp.int32)
-    z = jnp.zeros(3, jnp.int32)
     on = jnp.ones(3, bool)
-    st, hit, filled = tlb_mod.access_fused(st, vpn, z, on, on, 1, n_waves=3)
+    st, hit, filled = tlb_mod.access_fused(st, vpn, on, on, 1, n_waves=3)
     assert filled.tolist() == [True, False, False]
     assert hit.tolist() == [False, True, True]
     assert int((st.tags >= 0).sum()) == 1    # exactly one entry installed
@@ -203,10 +202,9 @@ def test_access_fused_duplicate_suppression_same_position():
 def test_access_fused_respects_may_fill_and_active():
     st = _mini_cache()
     vpn = jnp.asarray([3, 7, 11], jnp.int32)
-    z = jnp.zeros(3, jnp.int32)
     active = jnp.asarray([True, True, False])
     may_fill = jnp.asarray([False, True, True])
-    st, hit, filled = tlb_mod.access_fused(st, vpn, z, active, may_fill, 1,
+    st, hit, filled = tlb_mod.access_fused(st, vpn, active, may_fill, 1,
                                            n_waves=3)
     assert filled.tolist() == [False, True, False]
     assert hit.tolist() == [False, False, False]
@@ -224,10 +222,49 @@ def test_access_fused_matches_probe_on_resident_lines():
     for i in range(4):
         st = tlb_mod.fill(st, vpn[i:i + 1], z[:1], on[:1], i + 1)
     via_probe, hit_p = tlb_mod.probe(st, vpn, z, on, 9)
-    via_fused, hit_f, filled = tlb_mod.access_fused(st, vpn, z, on, on, 9)
+    via_fused, hit_f, filled = tlb_mod.access_fused(st, vpn, on, on, 9)
     assert bool(hit_p.all()) and bool(hit_f.all()) and not bool(filled.any())
     for leaf_p, leaf_f in zip(via_probe, via_fused):
         np.testing.assert_array_equal(np.asarray(leaf_p), np.asarray(leaf_f))
+
+
+def _resident_cache(rng, n_sets, n_ways):
+    """A cache as the round leaves it: each set holds distinct lines of
+    that set, some ways empty (-1), with arbitrary last-use times."""
+    lines = np.stack([rng.permutation(2 * n_ways)[:n_ways] * n_sets + s
+                      for s in range(n_sets)])
+    tags = np.where(rng.rand(n_sets, n_ways) < 0.2, -1, lines)
+    lru = rng.randint(0, 100, (n_sets, n_ways))
+    return jnp.asarray(tags, jnp.int32), jnp.asarray(lru, jnp.int32)
+
+
+@pytest.mark.parametrize("sets,ways,N,W", [
+    (1, 64, 30, 1), (32, 16, 30, 3), (64, 8, 64, 4), (4, 2, 24, 6),
+    (1024, 16, 240, 8),      # Table 1 L2$: 30 cores x (4 walk + 4 data)
+    (64, 16, 120, 4),        # Table 1 PWC: 30 cores x 4 walk levels
+])
+def test_access_fused_equals_reference_line_round(sets, ways, N, W):
+    """`access_fused` == the plain reference's `line_round`: tags, LRU
+    and hits, bit for bit, over lanes that crowd a few sets."""
+    for seed in range(3):
+        rng = np.random.RandomState(seed * 1000 + sets + W)
+        tags, lru = _resident_cache(rng, sets, ways)
+        line = jnp.asarray(rng.randint(0, min(sets, 8), N)
+                           + sets * rng.randint(0, 2 * ways, N), jnp.int32)
+        active = jnp.asarray(rng.rand(N) > 0.25)
+        may_fill = jnp.asarray(rng.rand(N) > 0.2)
+        st = tlb_mod.TLBState(tags=tags, asids=jnp.full_like(tags, -1),
+                              lru=lru, hits=jnp.zeros((), jnp.int32),
+                              misses=jnp.zeros((), jnp.int32))
+        st, hit, _ = tlb_mod.access_fused(st, line, active, may_fill, 777,
+                                          n_waves=W)
+        want, want_hit = reference.line_round(
+            dict(tag=tags, lru=lru), line, active, may_fill, 777, W)
+        for got, exp, what in ((st.tags, want["tag"], "tags"),
+                               (st.lru, want["lru"], "lru"),
+                               (hit, want_hit, "hit")):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(exp),
+                                          err_msg=f"{what} seed={seed}")
 
 
 # ---------------------------- fused pipeline vs sequential reference
@@ -253,7 +290,7 @@ TOL = {
 }
 
 
-@pytest.mark.parametrize("name", ALL_DESIGNS)
+@pytest.mark.parametrize("name", PAPER_DESIGNS)
 @pytest.mark.parametrize("n_apps", [1, 2, 3])
 def test_fused_pipeline_tracks_sequential_reference(name, n_apps):
     """The fused one-round-per-cycle pipeline reproduces the sequential

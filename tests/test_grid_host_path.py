@@ -17,9 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.design import (canonical_design, design_params, get_design,
-                               host_design_params, static_signature)
-from repro.core.mask import ALL_DESIGNS
+from repro.core.design import (PAPER_DESIGNS, canonical_design,
+                               design_params, get_design, host_design_params,
+                               static_signature)
 from repro.sim import audit, runner
 from repro.sim.config import SimConfig
 from repro.sim.workloads import BENCHES, app_matrix, app_row, make_app
@@ -115,12 +115,12 @@ def test_grid_rows_equal_per_row_stats_of_whole_state(n_apps, pad_rows):
     mixes = list(MIXES[n_apps])
     if pad_rows:        # as `predict_mixes` pads: repeat the last row
         mixes += [mixes[-1]] * ((-len(mixes)) % pad_rows)
-    grid = runner.run_grid(list(ALL_DESIGNS), mixes, cycles=CYCLES)
+    grid = runner.run_grid(list(PAPER_DESIGNS), mixes, cycles=CYCLES)
     traces = runner.TRACE_COUNT
-    ref = _per_row_grid(list(ALL_DESIGNS), mixes, CYCLES)
+    ref = _per_row_grid(list(PAPER_DESIGNS), mixes, CYCLES)
     # the eagerly stacked rows hit the same compiled program: same avals
     assert runner.TRACE_COUNT == traces
-    for d, name in enumerate(ALL_DESIGNS):
+    for d, name in enumerate(PAPER_DESIGNS):
         for m in range(len(mixes)):
             assert _described(grid[d][m]) == _described(ref[d][m]), \
                 (name, mixes[m])
@@ -237,7 +237,7 @@ def _aval(x):
     return a.dtype, a.shape, a.weak_type
 
 
-@pytest.mark.parametrize("name", ALL_DESIGNS)
+@pytest.mark.parametrize("name", PAPER_DESIGNS)
 def test_host_design_params_have_design_params_avals(name):
     host, dev = host_design_params(name), design_params(name)
     assert all(isinstance(x, np.ndarray) for x in host)

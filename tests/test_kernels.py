@@ -8,8 +8,6 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
-from repro.kernels.fused_tlb.ops import fused_tlb_access
-from repro.kernels.fused_tlb.ref import fused_tlb_access_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_recurrence_ref
 
@@ -78,39 +76,6 @@ def test_ssd_scan_sweep(S, nh, hd, ds, chunk):
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("sets,ways,N,W", [(1, 64, 30, 1), (32, 16, 30, 3),
-                                           (64, 8, 64, 4), (4, 2, 24, 6)])
-@pytest.mark.parametrize("track_asids", [True, False])
-def test_fused_tlb_sweep(sets, ways, N, W, track_asids):
-    """Pallas fused round (interpret) == the XLA `access_fused` oracle,
-    bit for bit, across waves / fill masks / both ASID modes."""
-    rng = np.random.RandomState(sets * ways + W)
-    tags = jnp.asarray(rng.randint(-1, 500, (sets, ways)), jnp.int32)
-    asids = jnp.asarray(rng.randint(0, 3, (sets, ways)), jnp.int32)
-    lru = jnp.asarray(rng.randint(0, 100, (sets, ways)), jnp.int32)
-    vpn = jnp.asarray(rng.randint(0, 600, (N,)), jnp.int32)
-    asid = jnp.asarray(rng.randint(0, 3, (N,)), jnp.int32)
-    active = jnp.asarray(rng.rand(N) > 0.25)
-    may_fill = jnp.asarray(rng.rand(N) > 0.2)
-    out = fused_tlb_access(tags, asids, lru, vpn, asid, active, may_fill, 77,
-                           n_waves=W, track_asids=track_asids, interpret=True)
-    ref = fused_tlb_access_ref(tags, asids, lru, vpn, asid, active, may_fill,
-                               77, n_waves=W, track_asids=track_asids)
-    for a, b, name in zip(out, ref, ("tags", "asids", "lru", "hit", "filled")):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
-
-
-def test_fused_tlb_raises_without_pallas_lowering():
-    """No silent fallback: interpret=None on a platform without a Pallas
-    lowering must raise, not quietly interpret."""
-    if jax.default_backend() in ("tpu", "gpu"):
-        pytest.skip("real Pallas lowering available")
-    z = jnp.zeros((4, 2), jnp.int32)
-    v = jnp.zeros((8,), jnp.int32)
-    with pytest.raises(RuntimeError, match="no Pallas lowering"):
-        fused_tlb_access(z, z, z, v, v, v, v, 0)
-
-
 def _tiny_kernel_call(kernel):
     """One minimal call of each attention/SSD wrapper with interpret=None."""
     f32 = jnp.float32
@@ -131,8 +96,8 @@ def _tiny_kernel_call(kernel):
 @pytest.mark.parametrize("kernel", ["flash_attention", "paged_attention",
                                     "ssd_scan"])
 def test_kernels_raise_without_tpu_lowering(kernel):
-    """Same rule as fused_tlb: interpret=None lowers for real or raises;
-    interpret mode is never chosen quietly off the TPU."""
+    """interpret=None lowers for real or raises; interpret mode is never
+    chosen quietly off the TPU."""
     if jax.default_backend() == "tpu":
         pytest.skip("real Pallas lowering available")
     with pytest.raises(RuntimeError, match="no Pallas lowering"):

@@ -14,8 +14,8 @@ import pytest
 
 from repro.core import tlb as tlb_mod
 from repro.core import tokens as tok_mod
-from repro.core.design import design_params
-from repro.core.mask import design, static_partition_index
+from repro.core.design import (design_params, get_design,
+                               static_partition_index)
 from repro.sim import memsys
 from repro.sim.config import SimConfig
 from repro.sim.golden import GOLDEN, golden_mismatches
@@ -24,7 +24,7 @@ from repro.sim.workloads import (FIELD, IDLE_ROW, N_FIELDS, app_matrix,
                                  mix_workloads, pair_workloads)
 
 SMALL = SimConfig(n_cores=4, warps_per_core=4, n_apps=2, sim_cycles=64,
-                  design=design("gpu-mmu"))
+                  design=get_design("gpu-mmu"))
 CYCLES = 1_200
 
 
@@ -286,7 +286,7 @@ def _reference_run(design_name, rows, cycles):
     wrapper equivalence tests are not tautologies."""
     from repro.sim import runner
     cfg = SimConfig(n_apps=len(rows), sim_cycles=cycles,
-                    design=design(design_name))
+                    design=get_design(design_name))
     pm = jnp.asarray(np.stack(rows))
     return runner._stats(cfg, runner._compiled_run(cfg)(pm))
 
@@ -328,7 +328,7 @@ def test_run_mix_three_apps_under_jit():
         assert np.all(np.isfinite(np.asarray(v, np.float64))), k
 
 
-# ------------------------------------------- design(name) compat vs goldens
+# ------------------------------------------------- built-in designs
 
 # The float-hex goldens live in `repro.sim.golden` so that the chip
 # smoke (`chip_smoke.py`) checks the very same table on the TPU.
@@ -343,29 +343,24 @@ def test_design_bitforbit_vs_goldens(entry):
 
 
 def test_design_shim_legacy_fields_pinned():
-    """The registry-served designs expose exactly the legacy DesignPoint
-    field values of the pre-redesign table (pinned here verbatim)."""
-    from repro.core.mask import ALL_DESIGNS, MaskConfig, design
-    base_off = MaskConfig(tlb_tokens=False, l2_bypass=False,
-                          dram_sched=False)
+    """The paper's eight built-in designs, pinned spec by spec: the
+    translation kind, the partition kind, and which of tokens / bypass /
+    MASK DRAM scheduling is on."""
+    from repro.core.design import PAPER_DESIGNS
     expect = {
-        # name: (use_l2_tlb, use_pwc, ideal_tlb, static_partition, mask)
-        "ideal": (True, False, True, False, base_off),
-        "pwc": (False, True, False, False, base_off),
-        "gpu-mmu": (True, False, False, False, base_off),
-        "static": (True, False, False, True, base_off),
-        "mask": (True, False, False, False, MaskConfig()),
-        "mask-tlb": (True, False, False, False, MaskConfig(
-            tlb_tokens=True, l2_bypass=False, dram_sched=False)),
-        "mask-cache": (True, False, False, False, MaskConfig(
-            tlb_tokens=False, l2_bypass=True, dram_sched=False)),
-        "mask-dram": (True, False, False, False, MaskConfig(
-            tlb_tokens=False, l2_bypass=False, dram_sched=True)),
+        # name: (translation, partition, tokens, bypass, dram)
+        "ideal": ("ideal", "shared", False, False, False),
+        "pwc": ("pwc", "shared", False, False, False),
+        "gpu-mmu": ("shared_l2_tlb", "shared", False, False, False),
+        "static": ("shared_l2_tlb", "static", False, False, False),
+        "mask": ("shared_l2_tlb", "shared", True, True, True),
+        "mask-tlb": ("shared_l2_tlb", "shared", True, False, False),
+        "mask-cache": ("shared_l2_tlb", "shared", False, True, False),
+        "mask-dram": ("shared_l2_tlb", "shared", False, False, True),
     }
-    assert set(ALL_DESIGNS) == set(expect)
-    for name, (l2, pwc, ideal, static, mask_cfg) in expect.items():
-        d = design(name)
+    assert set(PAPER_DESIGNS) == set(expect)
+    for name, want in expect.items():
+        d = get_design(name)
         assert d.name == name
-        assert (d.use_l2_tlb, d.use_pwc, d.ideal_tlb,
-                d.static_partition) == (l2, pwc, ideal, static), name
-        assert d.mask == mask_cfg, name
+        assert (d.translation.kind, d.partition.kind, d.tokens.enabled,
+                d.bypass.enabled, d.dram.enabled) == want, name

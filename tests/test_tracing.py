@@ -7,8 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.design import design_params
-from repro.core.mask import design
+from repro.core.design import design_params, get_design
 from repro.sim import runner
 from repro.sim.config import SimConfig
 
@@ -21,8 +20,8 @@ NESTED = ("mem.fused_tlb", "mem.dram")
 @pytest.mark.parametrize("name", ["mask", "ideal"])
 def test_every_stage_scope_is_in_the_op_metadata(name):
     cfg = runner._canonical(SimConfig(n_cores=4, warps_per_core=4, n_apps=2,
-                                      sim_cycles=4, design=design(name)))
-    dp = design_params(design(name))
+                                      sim_cycles=4, design=get_design(name)))
+    dp = design_params(get_design(name))
     pm = jnp.asarray(runner._mix_matrix(["3DS", "BLK"]))
     text = jax.jit(runner._run_fn(cfg)).lower(dp, pm).as_text(
         debug_info=True)
@@ -89,8 +88,8 @@ def test_membership_scope_is_in_the_segment_program():
     from repro.sim import faults
     from repro.sim.memsys import init_state
     cfg = runner._canonical(SimConfig(n_cores=4, warps_per_core=4, n_apps=2,
-                                      sim_cycles=4, design=design("mask")))
-    dp = design_params(design("mask"))
+                                      sim_cycles=4, design=get_design("mask")))
+    dp = design_params(get_design("mask"))
     pm = jnp.asarray(runner._mix_matrix(["3DS", "BLK"]))
     fops = jax.tree_util.tree_map(lambda x: x[0],
                                   faults.empty_operands(cfg, 1))
