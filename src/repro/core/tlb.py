@@ -165,10 +165,15 @@ def access_fused(state: TLBState, line, active, may_fill, time,
       * victims chain like sequential LRU: the k-th winning wave in a set
         takes the k-th least-recently-used way (stable (lru, way) order).
 
-    Everything is O(N·ways²) gathers/scatters and small per-wave blocks —
-    deliberately NO (N, N) lane matrices and no sort: on XLA CPU those
-    dominated the entire cycle (argsort of the LRU rows alone cost more
-    than the eight sequential rounds it replaced).
+    The fill port is dense compares, not a scatter: the winner of each
+    (set, wave) is a (waves, C, C) same-set compare over the wave's lanes,
+    and which earlier waves filled a lane's set is an (N, N) same-set
+    compare over every lane. No scratch table is written: XLA:TPU applies
+    a scatter's indices one after another, so under the grid's row vmap
+    such a table costs a serial update per lane and row. The rest is
+    O(N·ways²): the row gathers, the pairwise LRU rank and the two plane
+    scatters. There is still no sort: on XLA CPU an argsort of the LRU
+    rows cost more than the eight sequential rounds this call replaced.
 
     Known deviations from running the waves sequentially: victim choice
     uses start-of-cycle LRU (a way probe-hit this cycle can be evicted by
@@ -209,17 +214,20 @@ def access_fused(state: TLBState, line, active, may_fill, time,
                & tri_w & cand_wc[:, None, :]).any(0).reshape(N)
         cand = cand & ~dup
 
-    # ---- per-(set, wave) fill port via a scratch table --------------------
-    # first candidate per (set, wave) wins; the occupied slots also give
-    # every lane its same-set earlier-wave winner count (the LRU rank)
+    # ---- per-(set, wave) fill port as dense lane compares ----------------
+    # a candidate wins its wave's port for its set unless a lower-indexed
+    # candidate of the same wave has the same set
+    set_wc = set_ix.reshape(W, C)
+    cand_wc = cand.reshape(W, C)
+    tri_c = jnp.arange(C)[None, :] < jnp.arange(C)[:, None]   # (C, C) j < i
+    beaten = ((set_wc[:, :, None] == set_wc[:, None, :]) & tri_c
+              & cand_wc[:, None, :]).any(-1).reshape(N)
+    winner = cand & ~beaten
+    # filled_sw[i, w]: some candidate of wave w has lane i's set; the
+    # same-set earlier waves give every lane its LRU rank
+    filled_sw = ((set_ix[:, None] == set_ix[None, :]) & cand[None, :]
+                 ).reshape(N, W, C).any(-1)                     # (N, W)
     wave = jnp.repeat(jnp.arange(W, dtype=jnp.int32), C)
-    order = jnp.arange(N, dtype=jnp.int32)
-    key = set_ix * W + wave
-    scratch = jnp.full((n_sets * W,), jnp.int32(N), jnp.int32)
-    scratch = scratch.at[jnp.where(cand, key, n_sets * W)].min(
-        order, mode="drop")
-    winner = cand & (scratch[key] == order)
-    filled_sw = (scratch.reshape(n_sets, W) < N)[set_ix]        # (N, W)
     earlier_w = jnp.arange(W)[None, :] < wave[:, None]          # (N, W)
     rank = (filled_sw & earlier_w).sum(1)
     # a set holds at most n_ways fills per cycle: with more winning waves

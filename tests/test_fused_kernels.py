@@ -267,6 +267,144 @@ def test_access_fused_equals_reference_line_round(sets, ways, N, W):
                                           err_msg=f"{what} seed={seed}")
 
 
+def _lane_loop_round(tags, lru, line, active, may_fill, t, W):
+    """The round's fill port as a plain per-lane loop: per (set, wave) the
+    lowest candidate lane fills; the k-th filling wave of a set takes the
+    k-th way of the start-of-cycle (lru, way) order; a set takes at most
+    n_ways fills. Writes land in lane order. Returns (tags, lru, hit,
+    filled, cut), `cut` counting port winners lost to the n_ways cap."""
+    tags0, lru0 = np.asarray(tags), np.asarray(lru)
+    tags, lru = tags0.copy(), lru0.copy()
+    n_sets, n_ways = tags.shape
+    N = len(line)
+    C = N // W
+    line = [int(x) for x in line]
+    sets = [x % n_sets for x in line]
+    pre_hit, way = [], []
+    for i in range(N):
+        ways_hit = [k for k in range(n_ways) if tags0[sets[i], k] == line[i]]
+        pre_hit.append(bool(active[i]) and bool(ways_hit))
+        way.append(ways_hit[0] if ways_hit else 0)
+    cand = [bool(active[i]) and not pre_hit[i] and bool(may_fill[i])
+            for i in range(N)]
+    # the same position (core) in an earlier wave already fills this line
+    cand = [cand[i] and not any(cand[v * C + i % C]
+                                and line[v * C + i % C] == line[i]
+                                for v in range(i // C))
+            for i in range(N)]
+    port = {}
+    for i in range(N):
+        if cand[i]:
+            port.setdefault((sets[i], i // C), i)
+    filled, writes, cut = [False] * N, [], 0
+    for i in range(N):
+        if pre_hit[i]:
+            writes.append((i, way[i]))
+        elif port.get((sets[i], i // C)) == i:
+            k = sum((sets[i], v) in port for v in range(i // C))
+            if k < n_ways:
+                order = sorted(range(n_ways),
+                               key=lambda w: (lru0[sets[i], w], w))
+                filled[i] = True
+                writes.append((i, order[k]))
+            else:
+                cut += 1
+    for i, w in writes:
+        tags[sets[i], w] = line[i]
+        lru[sets[i], w] = t
+    hit = [pre_hit[i] or (bool(active[i]) and not filled[i]
+                          and line[i] in tags[sets[i]].tolist())
+           for i in range(N)]
+    return tags, lru, np.asarray(hit, bool), np.asarray(filled, bool), cut
+
+
+def _port_case(rng, sets, ways, N, draw):
+    tags, lru = _resident_cache(rng, sets, ways)
+    crowd = 1 if draw == "one_set" else min(sets, 8)
+    line = (rng.randint(0, crowd, N) + sets * rng.randint(0, 2 * ways, N))
+    active = rng.rand(N) > 0.25 if draw != "inactive" else np.zeros(N, bool)
+    may_fill = rng.rand(N) > 0.2
+    return (tags, lru, jnp.asarray(line, jnp.int32), jnp.asarray(active),
+            jnp.asarray(may_fill))
+
+
+@pytest.mark.parametrize("sets,ways,N,W,draw,rows", [
+    (32, 4, 30, 1, "crowded", None),
+    (16, 4, 32, 4, "crowded", None),
+    (16, 4, 32, 8, "crowded", None),
+    (1024, 16, 240, 8, "crowded", None),     # Table 1 L2$
+    (64, 16, 120, 4, "crowded", None),       # Table 1 PWC
+    (16, 4, 32, 4, "inactive", None),
+    (16, 4, 32, 8, "one_set", None),
+    (4, 2, 48, 16, "one_set", None),         # W > ways: the overflow cut
+    (16, 4, 0, 4, "crowded", None),          # N = 0
+    (1024, 16, 240, 8, "crowded", 3),        # vmapped over rows, as a grid
+])
+def test_access_fused_fill_port_matches_lane_loop(sets, ways, N, W, draw,
+                                                  rows):
+    """`access_fused`'s fill port == a plain per-lane loop: filled, hit,
+    tags, LRU and the hit/miss counters, bit for bit."""
+    rng = np.random.RandomState(sets + N + W)
+    cases = [_port_case(rng, sets, ways, N, draw) for _ in range(rows or 1)]
+    stack = [jnp.stack(x) for x in zip(*cases)]
+    tags, lru, line, active, may_fill = stack
+    st = tlb_mod.TLBState(tags=tags, asids=jnp.full_like(tags, -1), lru=lru,
+                          hits=jnp.zeros(len(cases), jnp.int32),
+                          misses=jnp.zeros(len(cases), jnp.int32))
+
+    def round_(s, ln, a, m):
+        return tlb_mod.access_fused(s, ln, a, m, 777, n_waves=W)
+
+    if rows:
+        got_st, got_hit, got_filled = jax.jit(jax.vmap(round_))(
+            st, line, active, may_fill)
+    else:
+        one = jax.tree_util.tree_map(lambda x: x[0], (st, line, active,
+                                                      may_fill))
+        got = jax.jit(round_)(*one)
+        got_st, got_hit, got_filled = jax.tree_util.tree_map(
+            lambda x: x[None], got)
+    for r, (tg, lr, ln, a, m) in enumerate(cases):
+        w_tags, w_lru, w_hit, w_filled, cut = _lane_loop_round(
+            tg, lr, ln, a, m, 777, W)
+        for got_x, exp, what in (
+                (got_st.tags[r], w_tags, "tags"), (got_st.lru[r], w_lru, "lru"),
+                (got_hit[r], w_hit, "hit"), (got_filled[r], w_filled, "filled"),
+                (got_st.hits[r], w_hit.sum(), "hits"),
+                (got_st.misses[r], (np.asarray(a) & ~w_hit).sum(), "misses")):
+            np.testing.assert_array_equal(np.asarray(got_x), np.asarray(exp),
+                                          err_msg=f"{what} row={r}")
+        if W > ways and draw == "one_set":
+            assert cut > 0          # the case reaches the n_ways cap
+        if draw != "inactive" and N:
+            assert w_filled.any()   # the case exercises the port
+
+
+@pytest.mark.parametrize("sets,ways,N,W", [
+    (1024, 16, 240, 8),      # Table 1 L2$
+    (64, 16, 120, 4),        # Table 1 PWC
+])
+def test_access_fused_port_lowers_without_scratch_scatter(sets, ways, N, W):
+    """Vmapped over grid rows, the round lowers with exactly two scatters,
+    the tag and LRU planes, and none with a `minimum` combiner: the fill
+    port is dense compares, not a (set, wave) table, since XLA:TPU applies
+    a scatter's indices one after another."""
+    rows = 3
+    st = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((rows,) + x.shape, x.dtype),
+        tlb_mod.init(sets * ways, ways))
+    lanes = jax.ShapeDtypeStruct((rows, N), jnp.int32)
+    flags = jax.ShapeDtypeStruct((rows, N), jnp.bool_)
+    text = jax.jit(jax.vmap(lambda s, ln, a, m: tlb_mod.access_fused(
+        s, ln, a, m, 777, n_waves=W))).lower(st, lanes, flags,
+                                             flags).as_text()
+    assert "stablehlo.reduce" in text       # the text is the program's
+    scatters = text.split('"stablehlo.scatter"')[1:]
+    assert len(scatters) == 2
+    for op in scatters:
+        assert "minimum" not in op[:op.index("})")]
+
+
 # ---------------------------- fused pipeline vs sequential reference
 
 BENCHES3 = ["3DS", "BLK", "MUM"]
